@@ -27,6 +27,9 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.compat import setup_compilation_cache
+
+    setup_compilation_cache()
     from . import fig2_distortion, fig3_pca, fig4_gp1d, fig56_regression, fig7_sparse
     from . import kernels_bench, roofline, ablation_bits, hotpath_bench, serve_bench
     from . import mesh_bench, vq_bench, wire_bench, fault_bench, stream_bench

@@ -52,6 +52,7 @@ HOST_CALLBACK_PRIMITIVES = frozenset({
 COLLECTIVE_PRIMITIVES = frozenset({
     "psum",
     "psum2",  # shard_map's replication-rewrite spelling (check_rep=True)
+    "psum_invariant",  # shard_map's spelling under check_vma=True
     "all_gather",
     "all_gather_invariant",
     "all_to_all",

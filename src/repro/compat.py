@@ -1,29 +1,33 @@
-"""Version guards for the jax API surface this repo targets.
+"""Process-level JAX setup shared by the entry points.
 
-The codebase is written against the current jax API (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.set_mesh``,
-``jax.sharding.get_abstract_mesh``); older releases (<= 0.4.x) spell these
-``jax.experimental.shard_map.shard_map(check_rep=...)``, plain ``make_mesh``,
-``with mesh:`` and the thread-resources physical mesh.  Everything that needs
-one of these goes through this module so the rest of the tree stays written
-against the new spelling.
+* :func:`make_mesh` — every mesh this repo builds has Auto axes.
+* :func:`cost_analysis_dict` — ``compiled.cost_analysis()`` as a dict.
+* :func:`host_device_count_flags` / :func:`force_host_device_count` — the
+  one place that edits ``XLA_FLAGS`` (CPU placeholder devices).
+* :func:`setup_compilation_cache` — the persistent compilation cache at a
+  fixed path, called by every entry point that compiles at size.
 """
 from __future__ import annotations
 
-import contextlib
-from functools import partial
+import os
 
 import jax
 
 __all__ = [
-    "shard_map",
+    "CHECKOUT_ROOT",
+    "COMPILATION_CACHE_DIR",
     "make_mesh",
-    "set_mesh",
-    "get_abstract_mesh",
     "cost_analysis_dict",
     "host_device_count_flags",
     "force_host_device_count",
+    "setup_compilation_cache",
 ]
+
+# the source checkout this package runs from (src/repro/compat.py -> root)
+CHECKOUT_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+COMPILATION_CACHE_DIR = os.path.join(CHECKOUT_ROOT, ".jax_cache")
 
 
 def host_device_count_flags(n: int, existing: str = "") -> str:
@@ -42,81 +46,44 @@ def host_device_count_flags(n: int, existing: str = "") -> str:
 def force_host_device_count(n: int) -> None:
     """Set XLA_FLAGS in os.environ to force ``n`` host devices — must run
     before the jax backend initializes (first device query; importing jax is
-    fine).  Shared by launch/dryrun (512 placeholder devices), serve_gp
-    --mesh (one device per machine), and the mesh benchmark subprocess."""
-    import os
-
+    fine).  Only the host (CPU) platform reads the flag; an attached
+    accelerator keeps its own device count.  Shared by launch/dryrun (512
+    placeholder devices) and serve_gp --mesh (one device per machine)."""
     os.environ["XLA_FLAGS"] = host_device_count_flags(
         n, os.environ.get("XLA_FLAGS", "")
     )
 
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-
-    def shard_map(f=None, /, *, mesh, in_specs, out_specs, check_vma=True, **kw):
-        """New-style ``jax.shard_map``: keyword mesh/specs, ``check_vma``
-        (mapped to the old ``check_rep``)."""
-        if f is None:
-            return partial(
-                shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=check_vma, **kw,
-            )
-        return _old_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma, **kw,
-        )
-
-
-def make_mesh(axis_shapes, axis_names, *, axis_types=None, **kw):
-    """``jax.make_mesh`` with ``axis_types`` dropped when unsupported.
-
-    ``axis_types`` may be ``"auto"``/``"explicit"`` strings or actual
-    ``jax.sharding.AxisType`` members; on jax without AxisType every mesh is
-    implicitly Auto, which is what this repo uses everywhere.
-    """
-    AxisType = getattr(jax.sharding, "AxisType", None)
-    if AxisType is None:
-        return jax.make_mesh(axis_shapes, axis_names, **kw)
-    if axis_types is None:
-        axis_types = (AxisType.Auto,) * len(axis_names)
-    axis_types = tuple(
-        getattr(AxisType, t.capitalize()) if isinstance(t, str) else t
-        for t in axis_types
-    )
-    return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types, **kw)
-
-
-def set_mesh(mesh):
-    """``jax.set_mesh`` context manager; on old jax, entering the Mesh sets the
-    thread-resources env, which is what ``get_abstract_mesh`` falls back to."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh  # Mesh is itself a context manager on 0.4.x
-
-
-def get_abstract_mesh():
-    """Current mesh (abstract on new jax, physical thread-resources mesh on
-    old jax — both expose ``.shape``, ``.axis_names`` and work as the ``mesh=``
-    argument of :func:`shard_map`)."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    from jax._src import mesh as mesh_lib
-
-    return mesh_lib.thread_resources.env.physical_mesh
+def make_mesh(axis_shapes, axis_names, **kw):
+    """``jax.make_mesh`` with every axis Auto (the sharding-in-types Explicit
+    mode is not used anywhere in this repo)."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=auto, **kw)
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict.
+    """``compiled.cost_analysis()`` as a dict (None on backends that report
+    no cost model)."""
+    return dict(compiled.cost_analysis() or {})
 
-    Old jax returns a one-element list of per-device dicts; new jax returns the
-    dict directly; either may be None on some backends.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost or {})
+
+def setup_compilation_cache() -> str | None:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and no
+    other directory is set here.  Otherwise, on an accelerator, the cache
+    lives at the fixed :data:`COMPILATION_CACHE_DIR` of the checkout: the
+    path is part of what makes a later process hit, so it is never built
+    from a temporary name, a process id or the time.  Every program is
+    cached, however fast it compiled, so a second run in the same checkout
+    compiles nothing it has compiled before.  A CPU-only process (tests,
+    CI) keeps JAX's default, no cache (returns None): its compiles are cheap,
+    and parallel test workers would all write one directory."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        if jax.default_backend() == "cpu":
+            return None
+        path = COMPILATION_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path

@@ -30,6 +30,7 @@ import jax
 
 from .config import DGPConfig
 from .gp import GPParams
+from .linalg_safe import full_precision
 from .registry import PROTOCOLS
 from .protocols import base as _base
 from .protocols.base import FittedProtocol, split_machines
@@ -62,6 +63,7 @@ class DistributedGP:
 
     # -- lifecycle -----------------------------------------------------------
 
+    @full_precision
     def fit(
         self, X=None, y=None, m: int | None = None, *, parts=None, key=None,
         params: GPParams | None = None,

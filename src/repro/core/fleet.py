@@ -47,6 +47,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .linalg_safe import full_precision
 from .registry import FUSIONS
 from .protocols import base
 from .protocols import broadcast as _broadcast
@@ -270,6 +271,7 @@ class FleetStack:
     (batch shape, availability pattern).  Admits run off the hot path (host
     work per CACHE miss, not per request)."""
 
+    @full_precision
     def __init__(self, tenants, slots: int | None = None):
         items = list(tenants.items()) if isinstance(tenants, dict) \
             else list(tenants)
@@ -320,6 +322,7 @@ class FleetStack:
         """Resident tenant ids, least-recently-used first."""
         return tuple(self._rows)
 
+    @full_precision
     def admit(self, tenant, art: FittedProtocol) -> int:
         """Make ``tenant`` resident (write its leaves into one slot row) and
         return the row.  A re-admit refreshes the row in place; a full stack
@@ -387,6 +390,7 @@ class FleetStack:
         self._block_t = t
         return self._block
 
+    @full_precision
     def predict(self, tenants, Xq, avail=None):
         """Serve one mixed-tenant micro-batch in ONE dispatch.
 

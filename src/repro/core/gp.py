@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from .linalg_safe import DEFAULT_JITTER, chol_jittered, chol_safe
+from .nystrom import nystrom_nlml
 from .registry import KERNELS, KernelSpec, register_kernel
 
 __all__ = [
@@ -259,7 +260,10 @@ def train_gp(
     """Maximize marginal likelihood with Adam.
 
     ``gram_override(params) -> G`` lets distributed variants train on an
-    externally assembled (e.g. Nyström-completed, quantized) gram matrix.
+    externally assembled (e.g. quantized) gram matrix.  An override that
+    returns the Nyström pair ``(G_KK, G_KN)`` trains on the completed gram
+    (eq. 61) through :func:`~repro.core.nystrom.nystrom_nlml`, never forming
+    the N x N matrix.
 
     ``impl="scan"`` (default) runs the whole optimizer loop as ONE compiled
     ``jax.lax.scan`` program — one trace, one device dispatch for all
@@ -276,17 +280,23 @@ def train_gp(
 
     def loss(p):
         G = gram_override(p) if gram_override is not None else k(p, X)
+        if isinstance(G, tuple):  # the Nyström pair (G_KK, G_KN)
+            return nystrom_nlml(*G, y, jnp.exp(p.log_noise))
         return nlml_from_gram(G, y, jnp.exp(p.log_noise))
 
+    if impl not in ("scan", "loop"):
+        raise ValueError(f"unknown train impl {impl!r}")
     step = make_adam_step(loss, lr)
     m = jax.tree.map(jnp.zeros_like, params)
     v = jax.tree.map(jnp.zeros_like, params)
 
-    if impl == "loop":
+    if steps == 0:
+        pass  # nothing to train: no optimizer program is built or compiled
+    elif impl == "loop":
         jstep = jax.jit(step)
         for i in range(steps):
             params, m, v = jstep(jnp.float32(i), params, m, v)
-    elif impl == "scan":
+    else:
 
         @jax.jit
         def run(p, m, v):
@@ -299,6 +309,4 @@ def train_gp(
             return p, m, v
 
         params, m, v = run(params, m, v)
-    else:
-        raise ValueError(f"unknown train impl {impl!r}")
     return GPModel(kernel=kernel, params=params, X=X, y=y, gram_backend=gram_backend)

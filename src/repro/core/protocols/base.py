@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from ..gp import GPParams, gram_fn, prior_diag
+from ..linalg_safe import full_precision
 from ..nystrom import nystrom_complete
 from ..registry import PROTOCOLS, SCHEMES
 
@@ -412,6 +413,7 @@ def _apply_fit_faults(parts, cfg):
     return new_parts, removed
 
 
+@full_precision
 def fit(
     parts,
     bits_per_sample: int = 0,
@@ -553,6 +555,7 @@ def _availability(art: FittedProtocol, available):
     return jnp.asarray((av > 0).astype(np.float32))
 
 
+@full_precision
 def predict(art: FittedProtocol, X_star, available=None):
     """Serve one query batch from a fitted artifact: (mean, var) at X_star.
 
@@ -600,6 +603,7 @@ def update_trace_count(protocol: str = "center") -> int:
     return _UPDATE_TRACES[protocol]
 
 
+@full_precision
 def update(art: FittedProtocol, X_new, y_new, machine: int = 0) -> FittedProtocol:
     """Stream (X_new, y_new) arriving at ``machine`` into a fitted artifact.
 

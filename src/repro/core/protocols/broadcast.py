@@ -27,7 +27,6 @@ from ..gp import (
     train_gp,
 )
 from ..nystrom import (
-    nystrom_complete,
     nystrom_posterior,
     nystrom_factors,
     nystrom_apply,
@@ -162,9 +161,9 @@ def fit_broadcast_host(parts, cfg, params=None) -> HostBroadcastGP:
     y0 = jnp.concatenate([yj for _, yj in parts], axis=0)
     nc0 = parts[0][0].shape[0]
 
-    def gram0(p):
+    def gram0(p):  # the Nyström pair: train_gp completes it implicitly
         Xc = X0[:nc0]
-        return nystrom_complete(k(p, Xc), k(p, Xc, X0))
+        return k(p, Xc), k(p, Xc, X0)
 
     trained = train_gp(
         X0, y0, kernel=cfg.kernel, params=params, steps=cfg.steps, lr=cfg.lr,
@@ -382,10 +381,10 @@ def _fit_broadcast(parts, cfg, params=None) -> FittedProtocol:
         axis=0,
     )
 
-    def gram0(p):
+    def gram0(p):  # the Nyström pair: train_gp completes it implicitly
         G_KK = kernel_from_inner(kernel, p, ip_KK0, sq0, sq0)
         G_KN = kernel_from_inner(kernel, p, ip_KN0, sq0, sq_cols0)
-        return nystrom_complete(G_KK, G_KN)
+        return G_KK, G_KN
 
     trained = train_gp(
         X0, y0, kernel=kernel, params=params, steps=cfg.steps, lr=cfg.lr,

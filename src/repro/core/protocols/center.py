@@ -242,34 +242,42 @@ class CenterGP:
         self._ip("NN" if self.gram_mode == "direct" else "KN")
         return self
 
-    def _gram_pallas(self, params):
-        sq = self._ip("sq")
+    def _nystrom_blocks(self, params):
+        """The completed gram's first K rows, (G_KK, G_KN)."""
         K = self.n_center
-        if self.gram_mode == "direct":
-            return kernel_from_inner(self.kernel, params, self._ip("NN"), sq, sq)
-        ip_KN = self._ip("KN")
-        G_KK = kernel_from_inner(self.kernel, params, ip_KN[:, :K], sq[:K], sq[:K])
-        G_KN = kernel_from_inner(self.kernel, params, ip_KN, sq[:K], sq)
-        if self.gram_mode == "nystrom_fitc" and self.sq_norms is not None:
-            return nystrom_complete(G_KK, G_KN, exact_diag=self._exact_diag(params))
-        return nystrom_complete(G_KK, G_KN)
+        if self.gram_backend == "pallas":
+            sq, ip_KN = self._ip("sq"), self._ip("KN")
+            G_KK = kernel_from_inner(self.kernel, params, ip_KN[:, :K], sq[:K],
+                                     sq[:K])
+            return G_KK, kernel_from_inner(self.kernel, params, ip_KN, sq[:K],
+                                           sq)
+        k = gram_fn(self.kernel)
+        Xc = self.X_recon[:K]
+        return k(params, Xc), k(params, Xc, self.X_recon)
 
     def _gram(self, params):
-        if self.gram_backend == "pallas":
-            return self._gram_pallas(params)
-        k = gram_fn(self.kernel)
         if self.gram_mode == "direct":
             # beyond-paper: all blocks straight from the reconstructed points;
             # converges to the full GP as R -> inf (Nyström caps at rank K)
-            return k(params, self.X_recon)
-        Xc = self.X_recon[: self.n_center]
-        G_KK = k(params, Xc)
-        G_KN = k(params, Xc, self.X_recon)
+            if self.gram_backend == "pallas":
+                sq = self._ip("sq")
+                return kernel_from_inner(self.kernel, params, self._ip("NN"),
+                                         sq, sq)
+            return gram_fn(self.kernel)(params, self.X_recon)
+        G_KK, G_KN = self._nystrom_blocks(params)
         if self.gram_mode == "nystrom_fitc" and self.sq_norms is not None:
             # Snelson & Ghahramani: make the Nyström diagonal exact (the
             # correction acts like per-point noise, taming the rank-K inverse)
             return nystrom_complete(G_KK, G_KN, exact_diag=self._exact_diag(params))
         return nystrom_complete(G_KK, G_KN)
+
+    def _train_gram(self, params):
+        """What ``train_gp`` trains on: the Nyström pair itself in
+        ``nystrom`` mode (woodbury NLML, no N x N matrix), else the dense
+        gram of :meth:`_gram`."""
+        if self.gram_mode == "nystrom":
+            return self._nystrom_blocks(params)
+        return self._gram(params)
 
     def predict(self, X_star, available=None):
         # ``available`` is accepted for surface parity with the fused-family
@@ -324,7 +332,7 @@ class CenterGP:
             G_sK = kernel_from_inner(self.kernel, p, ip_sK, sq_star, sq[:K])
             G_KN = kernel_from_inner(self.kernel, p, ip_KN, sq[:K], sq)
             return nystrom_posterior(G_KK, G_KN, self.y, noise, G_sK, g_ss)
-        G = self._gram_pallas(p)
+        G = self._gram(p)
         if self.gram_mode == "nystrom_fitc":
             # FITC-consistent test covariance (see the xla path)
             ip_sK = gram_kernel(X_star, Xc)
@@ -386,7 +394,7 @@ def fit_center_host(parts, cfg, params: GPParams | None = None) -> CenterGP:
     )
     trained = train_gp(
         X_recon, y_all, kernel=cfg.kernel, params=model.params, steps=cfg.steps,
-        lr=cfg.lr, gram_override=model._gram, impl=cfg.train_impl,
+        lr=cfg.lr, gram_override=model._train_gram, impl=cfg.train_impl,
     )
     model.params = trained.params
     return model
@@ -473,7 +481,7 @@ def _fit_center(parts, cfg, params: GPParams | None = None) -> FittedProtocol:
     )
     trained = train_gp(
         X_recon, y_all, kernel=kernel, params=builder.params, steps=cfg.steps,
-        lr=cfg.lr, gram_override=builder._gram, impl=cfg.train_impl,
+        lr=cfg.lr, gram_override=builder._train_gram, impl=cfg.train_impl,
     )
     builder.params = trained.params
     p = builder.params

@@ -2,7 +2,7 @@
 
 The production SPMD substrate shared by every protocol: machines live along a
 1-D ``("machines",)`` device mesh, the per-symbol wire protocol runs as ONE
-``compat.shard_map`` program whose only inter-machine channel is
+``jax.shard_map`` program whose only inter-machine channel is
 ``repro.comm.q_all_gather`` (int codes + O(d²) fp32 side info; the ledger is
 computed from what the collective actually moves), per-machine factors are
 built device-local and live SHARDED along the mesh axis, and broadcast/PoE
@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ...compat import shard_map
+from jax import shard_map
 from .. import jax_scheme
 from ..gp import (
     GPParams,
@@ -55,15 +55,16 @@ MESH_AXIS = "machines"
 
 def machine_mesh(m: int) -> Mesh:
     """A 1-D ``("machines",)`` mesh over the first m local devices — the
-    execution substrate of ``impl="mesh"``.  On CPU, force placeholder
-    devices with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-    (tests/conftest.py does; launch/serve_gp.py --mesh does it for you)."""
+    execution substrate of ``impl="mesh"``.  m may not exceed the number of
+    attached devices (four on a v5e 2x2 host).  A CPU-only host can stand in
+    with placeholder devices, ``XLA_FLAGS=--xla_force_host_platform_device_
+    count=8`` (tests/conftest.py sets it)."""
     devs = jax.devices()
     if m > len(devs):
         raise ValueError(
             f'impl="mesh" needs one device per machine: m={m} > '
-            f"{len(devs)} available devices (hint: "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={m})"
+            f"{len(devs)} attached {devs[0].platform} devices — use at most "
+            f"{len(devs)} machines, or impl=\"batched\" for more"
         )
     return Mesh(np.asarray(devs[:m]), (MESH_AXIS,))
 

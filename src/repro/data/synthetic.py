@@ -11,6 +11,7 @@
 from __future__ import annotations
 
 import dataclasses
+import zlib
 
 import numpy as np
 import jax
@@ -46,15 +47,20 @@ def lm_batch_stream(vocab_size: int, batch: int, seq: int, seed: int = 0):
         step += 1
 
 
-def regression_dataset(name: str, seed: int = 0, data_dir: str | None = None):
+def regression_dataset(name: str, seed: int = 0, data_dir: str | None = None,
+                       n_train: int | None = None):
     """(X_train, y_train, X_test, y_test) float32, normalized like the paper:
-    inputs zero-mean unit-variance, targets centered."""
+    inputs zero-mean unit-variance, targets centered.  ``n_train`` overrides
+    the paper's training size (the dataset's input shape and target law are
+    unchanged); the data is a function of (name, seed) alone."""
     if data_dir is not None:
         loaded = _try_load_real(name, data_dir)
         if loaded is not None:
             return loaded
-    n_train, n_test, d = DATASET_SPECS[name]
-    rng = np.random.default_rng((hash(name) & 0xFFFF, seed))
+    spec_train, n_test, d = DATASET_SPECS[name]
+    n_train = spec_train if n_train is None else int(n_train)
+    # crc32, not hash(): str hashes are salted per process
+    rng = np.random.default_rng((zlib.crc32(name.encode()) & 0xFFFF, seed))
     # anisotropic inputs (random covariance); target roughness matched to the
     # real dataset's character (KIN40K is famously high-frequency/nonlinear,
     # SARCOS moderately smooth, ABALONE nearly monotone)

@@ -52,7 +52,7 @@ def _epilogue_kernel_path(G, Ainv, P, walpha, gss, prior, w, *, fuse,
     gssp = _pad_to(f32(gss)[None, :], LANE, 1, value=1.0)  # (1, tp)
     priorp = _pad_to(f32(prior)[None, :], LANE, 1, value=1.0)
     tp = gssp.shape[1]
-    wp = f32(w)[:, None] * jnp.ones((m, tp), jnp.float32)  # (m, tp)
+    wp = f32(w)[:, None, None] * jnp.ones((m, 1, tp), jnp.float32)  # (m, 1, tp)
     S = epilogue_pallas(Gp, Ap, Pp, wap, gssp, priorp, wp,
                         fuse=fuse, interpret=interpret)
     return S[:3, :t]
@@ -104,7 +104,7 @@ def _epilogue_fleet_kernel_path(G, Ainv, P, walpha, gss, prior, w, *, fuse,
     gssp = _pad_to(f32(gss)[:, None, :], LANE, 2, value=1.0)  # (T, 1, tp)
     priorp = _pad_to(f32(prior)[:, None, :], LANE, 2, value=1.0)
     tp = gssp.shape[2]
-    wp = f32(w)[:, :, None] * jnp.ones((T, m, tp), jnp.float32)  # (T, m, tp)
+    wp = f32(w)[:, :, None, None] * jnp.ones((T, m, 1, tp), jnp.float32)
     if block is not None and tp % int(block):
         block = None  # tuned tile from another shape bucket: full-t fallback
     S = epilogue_fleet_pallas(Gp, Ap, Pp, wap, gssp, priorp, wp,
@@ -161,7 +161,7 @@ def fleet_epilogue_block(T: int, m: int, t: int, K: int, *, fuse: str = "kl",
                 jnp.zeros((T, m, 1, Kp), jnp.float32),
                 jnp.ones((T, 1, tp), jnp.float32),
                 jnp.ones((T, 1, tp), jnp.float32),
-                jnp.ones((T, m, tp), jnp.float32),
+                jnp.ones((T, m, 1, tp), jnp.float32),
             )
         fn = lambda: epilogue_fleet_pallas(
             *ops, fuse=fuse, block=bt, interpret=d.interpret

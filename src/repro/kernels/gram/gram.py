@@ -28,6 +28,7 @@ def _gram_kernel(x_ref, y_ref, o_ref):
         y_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),  # X @ Y^T
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 

@@ -35,8 +35,13 @@ def _qgram_packed_kernel(
     words = words_ref[...]  # (bn, W) uint32
     W = words.shape[1]
     word_idx = meta_ref[0, :]  # (d,) int32
-    bit = meta_ref[1, :].astype(jnp.uint32)
-    width = meta_ref[2, :].astype(jnp.uint32)
+    bit_i = meta_ref[1, :]
+    width_i = meta_ref[2, :]
+    # shift amounts are clamped in int32 and only then cast: Mosaic has no
+    # unsigned max/min (arith.maxui / arith.minui do not legalize on TPU)
+    bit = bit_i.astype(jnp.uint32)
+    hi_shift = (_WORD - jnp.maximum(bit_i, 1)).astype(jnp.uint32)
+    lo_width = jnp.minimum(width_i, _WORD - 1).astype(jnp.uint32)
 
     # select each dimension's source word(s) with a static W-step select loop
     # (TPU-safe: no dynamic gather on the lane axis)
@@ -49,16 +54,11 @@ def _qgram_packed_kernel(
 
     lo = lo_src >> bit[None, :]
     hi = jnp.where(
-        bit[None, :] > 0,
-        hi_src << (_WORD - jnp.maximum(bit, jnp.uint32(1)))[None, :],
-        jnp.uint32(0),
+        bit_i[None, :] > 0, hi_src << hi_shift[None, :], jnp.uint32(0)
     )
     full = jnp.uint32(0xFFFFFFFF)
     wmask = jnp.where(
-        width >= _WORD,
-        full,
-        (jnp.uint32(1) << jnp.minimum(width, jnp.uint32(_WORD - 1)))
-        - jnp.uint32(1),
+        width_i >= _WORD, full, (jnp.uint32(1) << lo_width) - jnp.uint32(1)
     )
     codes = ((lo | hi) & wmask[None, :]).astype(jnp.int32)  # (bn, d) in VMEM
 
@@ -80,6 +80,7 @@ def _qgram_packed_kernel(
         y_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 

@@ -29,6 +29,7 @@ import dataclasses
 import functools
 import json
 import os
+import sys
 import tempfile
 import threading
 import time
@@ -36,6 +37,7 @@ from typing import Any, Callable, Iterable, Sequence
 
 import jax
 
+from ..compat import CHECKOUT_ROOT
 from ..core.registry import Registry
 
 # --------------------------------------------------------------------------
@@ -167,9 +169,12 @@ def interpret_autotune() -> bool:
 # File format (JSON, atomic-rename writes):
 #   {"version": 1, "entries": {"<key>": [bn, bp], ...}}
 # Key format (one string so the file stays greppable):
-#   <op>|<backend>|<shape>x<shape>...|<dtype>|bits=<b>|<extra...>
+#   <op>|<device_kind>|<shape>x<shape>...|<dtype>|bits=<b>|<extra...>
 # A corrupt, stale, or unreadable file is IGNORED (defaults / re-sweep), never
-# an error: the cache is an accelerant, not a dependency.
+# an error: the cache is an accelerant, not a dependency.  The default file
+# sits at a fixed path in the checkout, beside the compilation cache
+# (repro.compat.setup_compilation_cache), so a later process in the same
+# checkout finds the winners again.
 
 CACHE_VERSION = 1
 
@@ -180,8 +185,7 @@ _CACHE_LOCK = threading.Lock()
 
 def cache_path() -> str:
     return os.environ.get(
-        "REPRO_TUNE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro", "autotune.json"),
+        "REPRO_TUNE_CACHE", os.path.join(CHECKOUT_ROOT, ".autotune.json")
     )
 
 
@@ -192,9 +196,10 @@ def cache_key(
     bits: int | None = None,
     extra: Sequence[Any] = (),
 ) -> str:
-    """Build the (shape, dtype, bits, backend) cache key for one op call."""
+    """Build the (shape, dtype, bits, device kind) cache key for one op call
+    — a winner tuned on one chip generation is never served to another."""
     shape_sig = "x".join("-".join(str(int(s)) for s in shp) for shp in shapes)
-    parts = [op, jax.default_backend(), shape_sig, str(dtype)]
+    parts = [op, jax.devices()[0].device_kind, shape_sig, str(dtype)]
     if bits is not None:
         parts.append(f"bits={int(bits)}")
     parts.extend(str(e) for e in extra)
@@ -264,7 +269,9 @@ def autotune(
     in-process cache has one, else time ``measure(candidate)`` over the
     candidates (``None`` = candidate infeasible for this shape), persist the
     winner, and return it.  A warm hit performs ZERO sweeps — asserted by
-    tests/test_kernel_runtime.py across two processes."""
+    tests/test_kernel_runtime.py across two processes.  A candidate that
+    raises is reported on stderr and skipped; when no candidate could run at
+    all the sweep raises instead of persisting an untested default."""
     global _SWEEPS
     cands = [tuple(c) for c in candidates]
     with _CACHE_LOCK:
@@ -273,13 +280,22 @@ def autotune(
         return tuple(hit)
     _SWEEPS += 1
     best, best_t = tuple(default), float("inf")
+    failures = []
     for cand in cands:
         try:
             dt = measure(cand)
-        except Exception:
+        except Exception as e:  # noqa: BLE001 - one candidate's failure
+            failures.append(f"{cand}: {type(e).__name__}: {e}")
+            print(f"autotune {key}: candidate {cand} failed: "
+                  f"{type(e).__name__}: {e}", file=sys.stderr)
             continue
         if dt is not None and dt < best_t:
             best, best_t = cand, dt
+    if failures and best_t == float("inf"):
+        raise RuntimeError(
+            f"autotune {key}: no candidate ran ({len(failures)} failed): "
+            + "; ".join(failures)
+        )
     with _CACHE_LOCK:
         _store_cache(key, best)
     return best
@@ -331,7 +347,9 @@ def shape_sweep(
                 for _ in range(reps):
                     call()
                 us = (time.perf_counter() - t0) / reps * 1e6
-            except Exception:
+            except Exception as e:  # noqa: BLE001 - the row records nan
+                print(f"shape_sweep {op}/{label}/{bname} failed: "
+                      f"{type(e).__name__}: {e}", file=sys.stderr)
                 us = float("nan")
             rows.append((label, bname, us))
     return rows

@@ -38,7 +38,7 @@ from ..models.sharding import (
 )
 from .mesh import make_production_mesh, PEAK_FLOPS_BF16, HBM_BW, ICI_BW
 from ..roofline import analyze_hlo
-from ..compat import set_mesh, cost_analysis_dict
+from ..compat import cost_analysis_dict
 
 LONG_CONTEXT_OK = {"xlstm-125m", "zamba2-2.7b", "gemma2-2b"}
 
@@ -219,7 +219,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, verbose: bool = True):
     n_chips = mesh.devices.size
     t0 = time.time()
     fn, args, rules, donate = build_lowerable(arch, shape_name, mesh, multi_pod)
-    with set_mesh(mesh), logical_rules(rules):
+    with jax.set_mesh(mesh), logical_rules(rules):
         lowered = jax.jit(fn, donate_argnums=donate).lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

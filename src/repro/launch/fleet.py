@@ -321,10 +321,12 @@ def main():
     import tempfile
 
     import jax
+    from repro.compat import setup_compilation_cache
     from repro.core import DGPConfig, DistributedGP
     from repro.core.fleet import fleet_trace_count
     from repro.core.protocols import serve_trace_count
 
+    setup_compilation_cache()
     cfg = DGPConfig(
         protocol=args.protocol,
         gram_backend=args.gram_backend,

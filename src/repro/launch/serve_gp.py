@@ -170,9 +170,11 @@ def main():
     ap.add_argument("--stream-size", type=int, default=16,
                     help="points per streaming update")
     ap.add_argument("--mesh", action="store_true",
-                    help="machines-as-devices: force --m host devices (CPU) "
-                         "and run the wire protocol, factor builds, and "
-                         "serving as shard_map programs (impl='mesh')")
+                    help="machines-as-devices: one attached device per "
+                         "machine, so --m may not exceed the device count; "
+                         "runs the wire protocol, factor builds, and serving "
+                         "as shard_map programs (impl='mesh').  A CPU-only "
+                         "host gets --m placeholder host devices")
     ap.add_argument("--chaos", default=None,
                     help="fault-injection spec, e.g. 'drop:1,flip:0.01,"
                          "straggle:3@0.2' (see docs/fault_model.md); every "
@@ -203,17 +205,20 @@ def main():
     args = ap.parse_args()
 
     if args.mesh:
-        # must happen before the jax backend initializes
+        # must happen before the jax backend initializes; only the host
+        # platform reads it, so a chip host keeps its attached devices
         from repro.compat import force_host_device_count
 
         force_host_device_count(args.m)
 
     import numpy as np
     import jax
+    from repro.compat import setup_compilation_cache
     from repro.core import DGPConfig, DistributedGP
     from repro.analysis import check_contracts
     from repro.core.protocols import serve_trace_count
 
+    setup_compilation_cache()
     fusion = args.fusion
     if fusion is None:
         fusion = "rbcm" if args.protocol == "poe" else "kl"

@@ -25,7 +25,8 @@ from jax.sharding import PartitionSpec as P
 
 from .layers import _init, mlp_apply, init_mlp
 from .sharding import constrain, current_rules, _mesh_sizes
-from ..compat import shard_map, get_abstract_mesh
+from jax import shard_map
+from jax.sharding import get_abstract_mesh
 
 
 def init_moe(key, cfg):

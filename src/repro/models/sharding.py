@@ -44,7 +44,7 @@ def resolve(*logical_names) -> P:
 
 def _mesh_sizes():
     try:
-        from ..compat import get_abstract_mesh
+        from jax.sharding import get_abstract_mesh
 
         am = get_abstract_mesh()
         return dict(am.shape) if am.axis_names else None

@@ -16,7 +16,8 @@ from .config import ModelConfig
 from .backbone import forward, init_model
 from .decode import decode_step as _decode_step, init_decode_state
 from ..optim import AdamWState, adamw_init, adamw_update, cosine_warmup
-from ..compat import shard_map, get_abstract_mesh
+from jax import shard_map
+from jax.sharding import get_abstract_mesh
 
 MOE_AUX_WEIGHT = 0.01
 ROUTER_Z_WEIGHT = 1e-3

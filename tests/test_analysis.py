@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.compat import make_mesh, shard_map
+from jax import shard_map
+from repro.compat import make_mesh
 from repro.core import split_machines, fit, predict
 from repro.core.protocols import serve_trace_count
 from repro.analysis import (
@@ -101,9 +102,10 @@ def test_walk_descends_into_shard_map():
                   mesh=mesh, in_specs=P("m"), out_specs=P())
     cj = jax.make_jaxpr(f)(jnp.ones(len(devs)))
     stats = collective_stats(cj)
-    # check_rep=True shard_map spells the reduction psum2; either counts
+    # shard_map rewrites the reduction as psum2 (check_rep) or
+    # psum_invariant (check_vma); every spelling counts
     (name,) = stats.keys()
-    assert name in ("psum", "psum2")
+    assert name in ("psum", "psum2", "psum_invariant")
     assert stats[name]["count"] == 1
     assert stats[name]["bytes"] == 4  # one f32 scalar per participant
 

@@ -7,9 +7,9 @@ original artifact produced (``expected.npz``).  Locked here:
 
   * ``load_artifact`` reads it, defaults the scheme to ``per_symbol``, and
     reconstructs a ``DGPConfig`` from the legacy metadata;
-  * predictions from the restored artifact match the recorded ones bitwise
-    (the serve path is unchanged by the metadata upgrade);
-  * re-saving writes a format-version-2 checkpoint (config recorded) that
+  * predictions from the restored artifact match the recorded ones to
+    ``RECORDED_ATOL`` (the serve path is unchanged by the metadata upgrade);
+  * re-saving writes a current-format checkpoint (config recorded) that
     round-trips bitwise.
 """
 import json
@@ -23,6 +23,18 @@ from repro.core.config import ARTIFACT_FORMAT_VERSION
 from repro.core.protocols import load_artifact, predict, save_artifact, update
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures", "legacy_artifact")
+
+# expected.npz was recorded under an older jax, whose XLA CPU code generation
+# rounds float32 differently (fusion order, vectorized exp).  The installed
+# jax reproduces it to 1.9e-6 in mu and 3.0e-6 in s2, on values of order
+# 0.1-1.4; 1e-5 leaves 3x headroom over that rounding drift and is still far
+# below what any change to the serve path itself would move.
+RECORDED_ATOL = 1e-5
+
+
+def _assert_recorded(got, want):
+    np.testing.assert_allclose(np.asarray(got), want, rtol=0,
+                               atol=RECORDED_ATOL)
 
 
 def _expected():
@@ -54,8 +66,8 @@ def test_legacy_artifact_loads_with_reconstructed_config():
     assert art.payload_bits == 0
     Xt, mu_exp, s2_exp = _expected()
     mu, s2 = predict(art, Xt)
-    np.testing.assert_array_equal(np.asarray(mu), mu_exp)
-    np.testing.assert_array_equal(np.asarray(s2), s2_exp)
+    _assert_recorded(mu, mu_exp)
+    _assert_recorded(s2, s2_exp)
 
 
 def test_legacy_artifact_roundtrips_to_current_format(tmp_path):
@@ -70,8 +82,12 @@ def test_legacy_artifact_roundtrips_to_current_format(tmp_path):
     assert art2.config == art.config
     Xt, mu_exp, s2_exp = _expected()
     mu, s2 = predict(art2, Xt)
-    np.testing.assert_array_equal(np.asarray(mu), mu_exp)
-    np.testing.assert_array_equal(np.asarray(s2), s2_exp)
+    _assert_recorded(mu, mu_exp)
+    _assert_recorded(s2, s2_exp)
+    # the re-saved checkpoint serves bitwise what the legacy load serves
+    mu0, s20 = predict(art, Xt)
+    np.testing.assert_array_equal(np.asarray(mu), np.asarray(mu0))
+    np.testing.assert_array_equal(np.asarray(s2), np.asarray(s20))
 
 
 def test_legacy_artifact_supports_streaming_and_facade():
@@ -81,7 +97,7 @@ def test_legacy_artifact_supports_streaming_and_facade():
     est = DistributedGP(art.config)
     Xt, mu_exp, _ = _expected()
     mu, _ = est.predict(art, Xt)
-    np.testing.assert_array_equal(np.asarray(mu), mu_exp)
+    _assert_recorded(mu, mu_exp)
     rng = np.random.default_rng(0)
     Xn = rng.normal(size=(4, Xt.shape[1])).astype(np.float32)
     art2 = update(art, Xn, np.zeros(4, np.float32), machine=1)

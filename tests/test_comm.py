@@ -14,7 +14,8 @@ import json
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.comm import q_all_gather, q_psum
-from repro.compat import shard_map, make_mesh
+from jax import shard_map
+from repro.compat import make_mesh
 
 mesh = make_mesh((8,), ("m",))
 rng = np.random.default_rng(0)
@@ -91,7 +92,7 @@ def _run_q_all_gather(m, n_loc, d, bits, seed=0):
     import numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.comm import q_all_gather
-    from repro.compat import shard_map
+    from jax import shard_map
 
     rng = np.random.default_rng(seed)
     X = (rng.normal(size=(m * n_loc, d))
@@ -145,7 +146,7 @@ def test_q_all_gather_state_ledger_matches_formula():
     from jax.sharding import PartitionSpec as P
     from repro.comm import q_all_gather
     from repro.comm.accounting import payload_bits_formula, side_info_bits
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core import jax_scheme
 
     m, n_loc, d = 4, 12, 5
@@ -207,7 +208,7 @@ def test_ledger_call_sites_integer_equal():
     import numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.comm import q_all_gather, wire_bits_all_gather
-    from repro.compat import shard_map
+    from jax import shard_map
 
     m, n_loc, d, bits = 4, 16, 6, 21
     rng = np.random.default_rng(3)
@@ -236,7 +237,7 @@ def test_q_psum_fp_fallback_is_exact():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from repro.comm import q_psum
-    from repro.compat import shard_map
+    from jax import shard_map
 
     m = 4
     G = np.stack([np.linspace(-1, 1, 128).astype(np.float32) * (i + 1)
@@ -258,7 +259,7 @@ def test_q_psum_gradient_straight_through(m):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from repro.comm import q_psum
-    from repro.compat import shard_map
+    from jax import shard_map
 
     rng = np.random.default_rng(m)
     G = jnp.asarray(rng.normal(size=(m, 256)).astype(np.float32))

@@ -409,7 +409,7 @@ def test_all_masked_shard_transmits_nothing():
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.comm import q_all_gather
     from repro.comm.accounting import side_info_bits, CRC_BITS
-    from repro.compat import shard_map
+    from jax import shard_map
 
     m, n_loc, d, bits = 4, 10, 5, 15
     rng = np.random.default_rng(15)
@@ -440,7 +440,7 @@ def test_q_all_gather_flip_fault_demotes_peers_not_self():
     (it never crossed the wire)."""
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.comm import q_all_gather
-    from repro.compat import shard_map
+    from jax import shard_map
 
     m, n_loc, d, bits = 4, 12, 5, 15
     rng = np.random.default_rng(16)

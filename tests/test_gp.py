@@ -7,7 +7,7 @@ import pytest
 from repro.core.gp import (
     init_params, linear_gram, se_gram, posterior_from_gram, nlml_from_gram, train_gp,
 )
-from repro.core.nystrom import nystrom_complete, nystrom_posterior
+from repro.core.nystrom import nystrom_complete, nystrom_nlml, nystrom_posterior
 
 
 def test_posterior_matches_naive_formula():
@@ -94,3 +94,32 @@ def test_nystrom_posterior_equals_dense_path():
     mu, var = nystrom_posterior(G_KK, G_KN, jnp.asarray(y), 0.2, G_sK, jnp.asarray(gss))
     np.testing.assert_allclose(np.asarray(mu), np.asarray(mu_ref), rtol=1e-2, atol=1e-2)
     np.testing.assert_allclose(np.asarray(var), np.asarray(var_ref), rtol=5e-2, atol=1e-2)
+
+
+@pytest.mark.parametrize("noise", [1e-2, 0.3])
+def test_nystrom_nlml_equals_dense_completed_nlml(noise):
+    """The woodbury training loss is the dense NLML of the completed gram,
+    value and gradient (checked in float64, where the two orderings of the
+    same algebra agree to rounding)."""
+    jax.config.update("jax_enable_x64", True)
+    try:
+        rng = np.random.default_rng(3)
+        N, K, d = 60, 12, 3
+        X = rng.normal(size=(N, d))
+        y = rng.normal(size=N)
+
+        def grams(log_ls):
+            p = init_params(1.0, 1.0, 0.1)._replace(log_b=log_ls)
+            Xc = jnp.asarray(X[:K])
+            return se_gram(p, Xc), se_gram(p, Xc, jnp.asarray(X))
+
+        dense = lambda ls: nlml_from_gram(nystrom_complete(*grams(ls)),
+                                          jnp.asarray(y), noise)
+        wood = lambda ls: nystrom_nlml(*grams(ls), jnp.asarray(y), noise)
+        ls = jnp.asarray(0.4, jnp.float64)
+        np.testing.assert_allclose(float(wood(ls)), float(dense(ls)),
+                                   rtol=1e-9)
+        np.testing.assert_allclose(float(jax.grad(wood)(ls)),
+                                   float(jax.grad(dense)(ls)), rtol=1e-6)
+    finally:
+        jax.config.update("jax_enable_x64", False)

@@ -182,7 +182,8 @@ def test_autotune_sweeps_once_then_warm_hits(monkeypatch, tmp_path):
     assert blob["entries"][key] == [1, 1]
 
 
-def test_autotune_infeasible_and_failing_candidates(monkeypatch, tmp_path):
+def test_autotune_infeasible_and_failing_candidates(monkeypatch, tmp_path,
+                                                    capsys):
     _with_cache(monkeypatch, tmp_path)
     key = runtime.cache_key("op2", [(4,)], "int8")
 
@@ -194,6 +195,26 @@ def test_autotune_infeasible_and_failing_candidates(monkeypatch, tmp_path):
         return 5.0
 
     assert runtime.autotune(key, [(1,), (2,), (3,)], measure, (1,)) == (3,)
+    # the failing candidate is reported, not swallowed
+    assert "candidate (2,) failed: RuntimeError: compile blew up" in \
+        capsys.readouterr().err
+
+
+def test_autotune_raises_when_every_candidate_fails(monkeypatch, tmp_path):
+    path = _with_cache(monkeypatch, tmp_path)
+    key = runtime.cache_key("op5", [(4,)], "float32")
+
+    def measure(c):
+        raise RuntimeError(f"no lowering for {c}")
+
+    with pytest.raises(RuntimeError, match="no candidate ran"):
+        runtime.autotune(key, [(1,), (2,)], measure, (1,))
+    assert not os.path.exists(path)  # nothing untested was persisted
+
+
+def test_cache_key_names_the_device_kind():
+    key = runtime.cache_key("op6", [(2, 3)], "float32", bits=4)
+    assert key.split("|")[1] == jax.devices()[0].device_kind
 
 
 def test_corrupt_or_stale_cache_falls_back(monkeypatch, tmp_path):

@@ -19,11 +19,11 @@ from repro.configs import get_config
 from repro.models import make_train_step
 from repro.models.steps import init_train_state
 from repro.models.sharding import logical_rules, rules_multi_pod
-from repro.compat import make_mesh, set_mesh
+from repro.compat import make_mesh
 
 mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = get_config("gemma2-2b").reduced()
-with set_mesh(mesh), logical_rules(rules_multi_pod()):
+with jax.set_mesh(mesh), logical_rules(rules_multi_pod()):
     params, opt = init_train_state(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(0)
     batch = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab_size, (8, 32)), jnp.int32)}
