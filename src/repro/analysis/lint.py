@@ -46,6 +46,11 @@ trace-counter-encapsulation
     trace-neutral snapshot/restore); everything else budgets retraces
     through ``repro.analysis.retrace_budget`` / the ``*_trace_count``
     wrappers.
+span-funnel
+    Profiler annotations (``TraceAnnotation``, ``StepTraceAnnotation``,
+    ``annotate_function``) only in ``repro/spans.py``: every span is named
+    ``repro.<name>`` and carries its fit's id, which the benchmark's
+    per-layer metrics read.
 """
 from __future__ import annotations
 
@@ -113,7 +118,11 @@ RULES = {
     "trace-counter-encapsulation":
         "_SERVE_TRACES/_UPDATE_TRACES touched outside core/protocols/ (use "
         "repro.analysis.retrace_budget)",
+    "span-funnel":
+        "profiler annotation outside repro/spans.py (use repro.spans.span)",
 }
+
+_ANNOTATIONS = ("TraceAnnotation", "StepTraceAnnotation", "annotate_function")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,6 +155,7 @@ class _FileKind:
 
     is_linalg_safe: bool
     is_compat: bool
+    is_spans: bool
     in_kernels: bool
     in_protocols: bool
     in_analysis: bool
@@ -157,6 +167,7 @@ class _FileKind:
         return cls(
             is_linalg_safe=p.endswith("core/linalg_safe.py"),
             is_compat=p.endswith("repro/compat.py"),
+            is_spans=p.endswith("repro/spans.py"),
             in_kernels="repro/kernels/" in p or p.startswith("kernels/"),
             in_protocols="core/protocols/" in p,
             in_analysis="repro/analysis/" in p,
@@ -274,6 +285,11 @@ class _Linter(ast.NodeVisitor):
                                "importing _JITTER (import "
                                "linalg_safe.DEFAULT_JITTER instead)")
         module = node.module or ""
+        if not self.kind.is_spans:
+            for alias in node.names:
+                if alias.name in _ANNOTATIONS:
+                    self._flag(node, "span-funnel",
+                               f"importing {alias.name} (use repro.spans.span)")
         if module.startswith("jax") and module.endswith("linalg") \
                 and not self.kind.is_linalg_safe:
             for alias in node.names:
@@ -296,6 +312,10 @@ class _Linter(ast.NodeVisitor):
         self.generic_visit(node)
 
     def visit_Attribute(self, node):
+        if node.attr in _ANNOTATIONS and not self.kind.is_spans:
+            self._flag(node, "span-funnel",
+                       f"{_dotted(node) or node.attr}: spans go through "
+                       "repro.spans.span")
         if node.attr in ("_SERVE_TRACES", "_UPDATE_TRACES") \
                 and not (self.kind.in_protocols or self.kind.in_analysis):
             self._flag(node, "trace-counter-encapsulation",

@@ -28,6 +28,7 @@ import dataclasses
 
 import jax
 
+from ..spans import fit_span
 from .config import DGPConfig
 from .gp import GPParams
 from .linalg_safe import full_precision
@@ -63,6 +64,7 @@ class DistributedGP:
 
     # -- lifecycle -----------------------------------------------------------
 
+    @fit_span()
     @full_precision
     def fit(
         self, X=None, y=None, m: int | None = None, *, parts=None, key=None,

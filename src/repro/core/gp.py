@@ -299,7 +299,7 @@ def train_gp(
     else:
 
         @jax.jit
-        def run(p, m, v):
+        def train_scan(p, m, v):
             def body(carry, i):
                 return step(i, *carry), None
 
@@ -308,5 +308,5 @@ def train_gp(
             )
             return p, m, v
 
-        params, m, v = run(params, m, v)
+        params, m, v = train_scan(params, m, v)
     return GPModel(kernel=kernel, params=params, X=X, y=y, gram_backend=gram_backend)

@@ -31,6 +31,7 @@ from ..gp import GPParams, gram_fn, prior_diag
 from ..linalg_safe import full_precision
 from ..nystrom import nystrom_complete
 from ..registry import PROTOCOLS, SCHEMES
+from ...spans import fit_span
 
 __all__ = [
     "split_machines",
@@ -413,6 +414,7 @@ def _apply_fit_faults(parts, cfg):
     return new_parts, removed
 
 
+@fit_span()
 @full_precision
 def fit(
     parts,

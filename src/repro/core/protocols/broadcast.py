@@ -37,6 +37,7 @@ from ..nystrom import (
 )
 from ..linalg_safe import DEFAULT_JITTER
 from ..registry import FUSIONS, SCHEMES, ProtocolSpec, register_protocol
+from ...spans import span
 from . import base, mesh
 from .base import (
     FittedProtocol,
@@ -324,180 +325,185 @@ def broadcast_gp(
 def _fit_broadcast(parts, cfg, params=None) -> FittedProtocol:
     from ...comm.accounting import row_bits
 
-    parts, _ = base._apply_fit_faults(parts, cfg)
-    m = len(parts)
-    shards = pad_parts(parts)
-    _, n_pad, d = shards.X.shape
-    bits, kernel, gram_mode = cfg.bits_per_sample, cfg.kernel, cfg.gram_mode
-    gram_backend, fuse = cfg.gram_backend, cfg.fusion
-    pack_bits = row_bits(bits, d, cfg.max_bits)
-    if cfg.impl == "mesh":
-        if gram_mode != "nystrom":
-            raise NotImplementedError(
-                'impl="mesh" broadcast supports gram_mode="nystrom" only'
+    with span("fit.wire"):
+        parts, _ = base._apply_fit_faults(parts, cfg)
+        m = len(parts)
+        shards = pad_parts(parts)
+        _, n_pad, d = shards.X.shape
+        bits, kernel, gram_mode = cfg.bits_per_sample, cfg.kernel, cfg.gram_mode
+        gram_backend, fuse = cfg.gram_backend, cfg.fusion
+        pack_bits = row_bits(bits, d, cfg.max_bits)
+        if cfg.impl == "mesh":
+            if gram_mode != "nystrom":
+                raise NotImplementedError(
+                    'impl="mesh" broadcast supports gram_mode="nystrom" only'
+                )
+            if gram_backend != "xla":
+                raise NotImplementedError(
+                    'impl="mesh" assembles grams device-local (gram_backend="xla")'
+                )
+        run = SCHEMES.get(cfg.scheme).run(
+            shards, bits, cfg.max_bits, "broadcast", 0, cfg.impl,
+            getattr(cfg, "faults", None),
+        )
+        # CRC demotion may have compacted rows out of the shard table: every
+        # assembly below reads the (possibly shrunk) post-wire shards
+        wire_state, shards = run.state, run.shards
+        wire, payload = run.wire_bits, run.payload_bits
+        extras = run.extras
+
+        sq_exact = jnp.sum(shards.X**2, -1)  # (m, n)
+        sq_dec = jnp.sum(wire_state.decoded**2, -1)
+        if cfg.impl != "mesh":  # the inner products the wire gives
+            A, B = _train_inner_products(shards, wire_state, gram_backend, pack_bits)
+
+    with span("fit.train"):
+        # ---- train shared hypers at machine 0 on its completed Nyström gram ----
+        # (unpadded slices; the inner products are param-independent constants, so
+        # the 150-step scan only re-does the cheap kernel map + Cholesky)
+        L = shards.lengths
+        n0 = L[0]
+        if cfg.impl == "mesh":
+            # machine-0-local training inputs, straight from the wire output (the
+            # batched A/B tensors below exist only to vmap the m simulated views)
+            X0s = jnp.asarray(shards.X[0, :n0], jnp.float32)
+            ip_KK0 = X0s @ X0s.T
+            X_cols0 = jnp.concatenate(
+                [X0s] + [wire_state.decoded[j, : L[j]] for j in range(1, m)], axis=0
             )
-        if gram_backend != "xla":
-            raise NotImplementedError(
-                'impl="mesh" assembles grams device-local (gram_backend="xla")'
+            ip_KN0 = X0s @ X_cols0.T
+        else:
+            ip_KK0 = A[0][:n0, :n0]
+            ip_KN0 = jnp.concatenate(
+                [ip_KK0] + [B[j, 0][: L[j], :n0].T for j in range(1, m)], axis=1
             )
-    run = SCHEMES.get(cfg.scheme).run(
-        shards, bits, cfg.max_bits, "broadcast", 0, cfg.impl,
-        getattr(cfg, "faults", None),
-    )
-    # CRC demotion may have compacted rows out of the shard table: every
-    # assembly below reads the (possibly shrunk) post-wire shards
-    wire_state, shards = run.state, run.shards
-    wire, payload = run.wire_bits, run.payload_bits
-    extras = run.extras
-
-    sq_exact = jnp.sum(shards.X**2, -1)  # (m, n)
-    sq_dec = jnp.sum(wire_state.decoded**2, -1)
-
-    # ---- train shared hypers at machine 0 on its completed Nyström gram ----
-    # (unpadded slices; the inner products are param-independent constants, so
-    # the 150-step scan only re-does the cheap kernel map + Cholesky)
-    L = shards.lengths
-    n0 = L[0]
-    if cfg.impl == "mesh":
-        # machine-0-local training inputs, straight from the wire output (the
-        # batched A/B tensors below exist only to vmap the m simulated views)
-        X0s = jnp.asarray(shards.X[0, :n0], jnp.float32)
-        ip_KK0 = X0s @ X0s.T
-        X_cols0 = jnp.concatenate(
-            [X0s] + [wire_state.decoded[j, : L[j]] for j in range(1, m)], axis=0
+        sq0 = sq_exact[0][:n0]
+        sq_cols0 = jnp.concatenate([sq0] + [sq_dec[j][: L[j]] for j in range(1, m)])
+        y0 = jnp.concatenate([shards.y[j, : L[j]] for j in range(m)], axis=0)
+        X0 = jnp.concatenate(
+            [shards.X[0, :n0]] + [wire_state.decoded[j, : L[j]] for j in range(1, m)],
+            axis=0,
         )
-        ip_KN0 = X0s @ X_cols0.T
-    else:
-        A, B = _train_inner_products(shards, wire_state, gram_backend, pack_bits)
-        ip_KK0 = A[0][:n0, :n0]
-        ip_KN0 = jnp.concatenate(
-            [ip_KK0] + [B[j, 0][: L[j], :n0].T for j in range(1, m)], axis=1
+
+        def gram0(p):  # the Nyström pair: train_gp completes it implicitly
+            G_KK = kernel_from_inner(kernel, p, ip_KK0, sq0, sq0)
+            G_KN = kernel_from_inner(kernel, p, ip_KN0, sq0, sq_cols0)
+            return G_KK, G_KN
+
+        trained = train_gp(
+            X0, y0, kernel=kernel, params=params, steps=cfg.steps, lr=cfg.lr,
+            gram_override=gram0, impl=cfg.train_impl,
         )
-    sq0 = sq_exact[0][:n0]
-    sq_cols0 = jnp.concatenate([sq0] + [sq_dec[j][: L[j]] for j in range(1, m)])
-    y0 = jnp.concatenate([shards.y[j, : L[j]] for j in range(m)], axis=0)
-    X0 = jnp.concatenate(
-        [shards.X[0, :n0]] + [wire_state.decoded[j, : L[j]] for j in range(1, m)],
-        axis=0,
-    )
+        p = trained.params
+        noise = jnp.exp(p.log_noise)
 
-    def gram0(p):  # the Nyström pair: train_gp completes it implicitly
-        G_KK = kernel_from_inner(kernel, p, ip_KK0, sq0, sq0)
-        G_KN = kernel_from_inner(kernel, p, ip_KN0, sq0, sq_cols0)
-        return G_KK, G_KN
+    with span("fit.factors"):
+        # ---- factorize every machine's local predictive under ONE vmap ----
+        mask_flat = shards.mask.reshape(-1)  # column layout is block j at slot j
+        y_flat = (shards.y * shards.mask).reshape(-1)
 
-    trained = train_gp(
-        X0, y0, kernel=kernel, params=params, steps=cfg.steps, lr=cfg.lr,
-        gram_override=gram0, impl=cfg.train_impl,
-    )
-    p = trained.params
-    noise = jnp.exp(p.log_noise)
+        fused_serve = getattr(cfg, "serve_epilogue", "fused") == "fused"
+        if cfg.impl == "mesh":
+            # one shard_map program: device i assembles & factorizes ITS view;
+            # the factor set lives sharded along the mesh axis
+            msh = mesh.machine_mesh(m)
+            factors = mesh._mesh_broadcast_factor_fn(m, kernel, fused_serve)(
+                shards.X, shards.mask, wire_state.decoded, sq_dec, mask_flat,
+                y_flat, p,
+            )
+            data = mesh._shard_machine_axis(
+                {"Xs": shards.X, "mask": shards.mask,
+                 "sq_exact": sq_exact, "sq_dec": sq_dec},
+                msh,
+            )
+            return FittedProtocol(
+                params=p, y=y_flat, factors=factors, data=data, wire=wire_state,
+                stream=StreamState.make(
+                    shards.lengths, y_flat.shape[0], int(wire), int(payload),
+                    int(run.integrity_bits), int(run.rows_demoted),
+                ),
+                protocol="broadcast", kernel=kernel, gram_mode=gram_mode,
+                fuse=fuse, gram_backend=gram_backend, n_center=0,
+                fit_lengths=shards.lengths, block_order=None,
+                bits_per_sample=bits, max_bits=cfg.max_bits, impl="mesh",
+                scheme=cfg.scheme, config=cfg,
+            )
 
-    # ---- factorize every machine's local predictive under ONE vmap ----
-    mask_flat = shards.mask.reshape(-1)  # column layout is block j at slot j
-    y_flat = (shards.y * shards.mask).reshape(-1)
+        if gram_mode == "nystrom":
 
-    fused_serve = getattr(cfg, "serve_epilogue", "fused") == "fused"
-    if cfg.impl == "mesh":
-        # one shard_map program: device i assembles & factorizes ITS view;
-        # the factor set lives sharded along the mesh axis
-        msh = mesh.machine_mesh(m)
-        factors = mesh._mesh_broadcast_factor_fn(m, kernel, fused_serve)(
-            shards.X, shards.mask, wire_state.decoded, sq_dec, mask_flat,
-            y_flat, p,
-        )
-        data = mesh._shard_machine_axis(
-            {"Xs": shards.X, "mask": shards.mask,
-             "sq_exact": sq_exact, "sq_dec": sq_dec},
-            msh,
-        )
+            def build(i):
+                mask_i = shards.mask[i]
+                # own (exact) block is the Nyström center; peers are reconstructions
+                ip_KK = A[i]
+                blocks = B[:, i].transpose(0, 2, 1)  # block j: Xs_i X̂_j^T (n, n)
+                blocks = blocks.at[i].set(ip_KK)  # own block exact
+                ip_KN = jnp.moveaxis(blocks, 0, 1).reshape(n_pad, m * n_pad)
+                sq_cols = sq_dec.at[i].set(sq_exact[i]).reshape(-1)
+                G_KK = _mask_gram(
+                    kernel_from_inner(kernel, p, ip_KK, sq_exact[i], sq_exact[i]),
+                    mask_i,
+                )
+                G_KN = kernel_from_inner(kernel, p, ip_KN, sq_exact[i], sq_cols) * (
+                    mask_i[:, None] * mask_flat[None, :]
+                )
+                fac = nystrom_factors(G_KK, G_KN, y_flat, noise)
+                if fused_serve:
+                    fac.update(nystrom_serve_cache(fac))
+                return fac
+
+            factors = jax.vmap(build)(jnp.arange(m))
+        elif gram_mode == "direct":
+            D = _decoded_inner_products(shards, wire_state, gram_backend, pack_bits)
+
+            def build(i):
+                mask_i = shards.mask[i]
+                own_cols = B[:, i].transpose(0, 2, 1)  # block j: Xs_i X̂_j^T
+                own_cols = own_cols.at[i].set(A[i])
+                row_i = jnp.moveaxis(own_cols, 0, 1).reshape(n_pad, m * n_pad)
+                # non-own rows: decoded-vs-decoded, with column block i swapped to
+                # decoded-vs-exact (B[r, i])
+                rows = D.reshape(m, n_pad, m, n_pad).at[:, :, i, :].set(B[:, i])
+                rows = rows.reshape(m, n_pad, m * n_pad).at[i].set(row_i)
+                ip_NN = rows.reshape(m * n_pad, m * n_pad)
+                sq_cols = sq_dec.at[i].set(sq_exact[i]).reshape(-1)
+                G = _mask_gram(
+                    kernel_from_inner(kernel, p, ip_NN, sq_cols, sq_cols), mask_flat
+                )
+                return posterior_factors(G, y_flat, noise)
+
+            factors = jax.vmap(build)(jnp.arange(m))
+        else:
+            raise ValueError(f"unknown broadcast gram mode {gram_mode!r}")
+
+        data = {
+            "Xs": shards.X, "mask": shards.mask,
+            "sq_exact": sq_exact, "sq_dec": sq_dec,
+        }
+        data.update(extras)
         return FittedProtocol(
-            params=p, y=y_flat, factors=factors, data=data, wire=wire_state,
+            params=p,
+            y=y_flat,
+            factors=factors,
+            data=data,
+            wire=wire_state,
             stream=StreamState.make(
                 shards.lengths, y_flat.shape[0], int(wire), int(payload),
                 int(run.integrity_bits), int(run.rows_demoted),
             ),
-            protocol="broadcast", kernel=kernel, gram_mode=gram_mode,
-            fuse=fuse, gram_backend=gram_backend, n_center=0,
-            fit_lengths=shards.lengths, block_order=None,
-            bits_per_sample=bits, max_bits=cfg.max_bits, impl="mesh",
-            scheme=cfg.scheme, config=cfg,
+            protocol="broadcast",
+            kernel=kernel,
+            gram_mode=gram_mode,
+            fuse=fuse,
+            gram_backend=gram_backend,
+            n_center=0,
+            fit_lengths=shards.lengths,
+            block_order=None,
+            bits_per_sample=bits,
+            max_bits=cfg.max_bits,
+            impl=cfg.impl,
+            scheme=cfg.scheme,
+            config=cfg,
         )
-
-    if gram_mode == "nystrom":
-
-        def build(i):
-            mask_i = shards.mask[i]
-            # own (exact) block is the Nyström center; peers are reconstructions
-            ip_KK = A[i]
-            blocks = B[:, i].transpose(0, 2, 1)  # block j: Xs_i X̂_j^T (n, n)
-            blocks = blocks.at[i].set(ip_KK)  # own block exact
-            ip_KN = jnp.moveaxis(blocks, 0, 1).reshape(n_pad, m * n_pad)
-            sq_cols = sq_dec.at[i].set(sq_exact[i]).reshape(-1)
-            G_KK = _mask_gram(
-                kernel_from_inner(kernel, p, ip_KK, sq_exact[i], sq_exact[i]), mask_i
-            )
-            G_KN = kernel_from_inner(kernel, p, ip_KN, sq_exact[i], sq_cols) * (
-                mask_i[:, None] * mask_flat[None, :]
-            )
-            fac = nystrom_factors(G_KK, G_KN, y_flat, noise)
-            if fused_serve:
-                fac.update(nystrom_serve_cache(fac))
-            return fac
-
-        factors = jax.vmap(build)(jnp.arange(m))
-    elif gram_mode == "direct":
-        D = _decoded_inner_products(shards, wire_state, gram_backend, pack_bits)
-
-        def build(i):
-            mask_i = shards.mask[i]
-            own_cols = B[:, i].transpose(0, 2, 1)  # block j: Xs_i X̂_j^T
-            own_cols = own_cols.at[i].set(A[i])
-            row_i = jnp.moveaxis(own_cols, 0, 1).reshape(n_pad, m * n_pad)
-            # non-own rows: decoded-vs-decoded, with column block i swapped to
-            # decoded-vs-exact (B[r, i])
-            rows = D.reshape(m, n_pad, m, n_pad).at[:, :, i, :].set(B[:, i])
-            rows = rows.reshape(m, n_pad, m * n_pad).at[i].set(row_i)
-            ip_NN = rows.reshape(m * n_pad, m * n_pad)
-            sq_cols = sq_dec.at[i].set(sq_exact[i]).reshape(-1)
-            G = _mask_gram(
-                kernel_from_inner(kernel, p, ip_NN, sq_cols, sq_cols), mask_flat
-            )
-            return posterior_factors(G, y_flat, noise)
-
-        factors = jax.vmap(build)(jnp.arange(m))
-    else:
-        raise ValueError(f"unknown broadcast gram mode {gram_mode!r}")
-
-    data = {
-        "Xs": shards.X, "mask": shards.mask,
-        "sq_exact": sq_exact, "sq_dec": sq_dec,
-    }
-    data.update(extras)
-    return FittedProtocol(
-        params=p,
-        y=y_flat,
-        factors=factors,
-        data=data,
-        wire=wire_state,
-        stream=StreamState.make(
-            shards.lengths, y_flat.shape[0], int(wire), int(payload),
-            int(run.integrity_bits), int(run.rows_demoted),
-        ),
-        protocol="broadcast",
-        kernel=kernel,
-        gram_mode=gram_mode,
-        fuse=fuse,
-        gram_backend=gram_backend,
-        n_center=0,
-        fit_lengths=shards.lengths,
-        block_order=None,
-        bits_per_sample=bits,
-        max_bits=cfg.max_bits,
-        impl=cfg.impl,
-        scheme=cfg.scheme,
-        config=cfg,
-    )
 
 
 def _predict_broadcast_experts(art, X_star, sq_star, g_ss, noise):

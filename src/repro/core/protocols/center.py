@@ -43,6 +43,7 @@ from ..nystrom import (
 )
 from ..linalg_safe import DEFAULT_JITTER, chol_jittered
 from ..registry import SCHEMES, ProtocolSpec, register_protocol
+from ...spans import span
 from . import base
 from .base import (
     FittedProtocol,
@@ -449,112 +450,117 @@ def _fit_center(parts, cfg, params: GPParams | None = None) -> FittedProtocol:
     from ...comm.accounting import row_bits
 
     _check_center(cfg, parts)
-    parts, _ = base._apply_fit_faults(parts, cfg)
-    (X_recon, y_all, wire, n_c, sq_norms, shards, wire_state, order, extras,
-     payload, integrity, rows_demoted) = (
-        _quantize_to_center_batched(
-            parts, cfg.bits_per_sample, cfg.center, cfg.max_bits, cfg.impl,
-            cfg.scheme, getattr(cfg, "faults", None),
+    with span("fit.wire"):
+        parts, _ = base._apply_fit_faults(parts, cfg)
+        (X_recon, y_all, wire, n_c, sq_norms, shards, wire_state, order, extras,
+         payload, integrity, rows_demoted) = (
+            _quantize_to_center_batched(
+                parts, cfg.bits_per_sample, cfg.center, cfg.max_bits, cfg.impl,
+                cfg.scheme, getattr(cfg, "faults", None),
+            )
         )
-    )
-    kernel, gram_mode, gram_backend = cfg.kernel, cfg.gram_mode, cfg.gram_backend
-    d = X_recon.shape[1]
-    if gram_mode == "nystrom_fitc":  # exact |x|^2 side-channel (32 bits/point)
-        wire += 32 * (X_recon.shape[0] - n_c)
-        payload += 32 * (X_recon.shape[0] - n_c)
-    builder = CenterGP(
-        kernel=kernel,
-        params=params or init_params(),
-        X_recon=X_recon,
-        y=y_all,
-        n_center=n_c,
-        wire_bits=wire,
-        gram_mode=gram_mode,
-        sq_norms=sq_norms,
-        gram_backend=gram_backend,
-        wire=wire_state,
-        block_order=tuple(order),
-        block_lengths=shards.lengths,
-        pack_bits=row_bits(cfg.bits_per_sample, d, cfg.max_bits),
-        payload_bits=payload,
-        integrity_bits=integrity,
-    )
-    trained = train_gp(
-        X_recon, y_all, kernel=kernel, params=builder.params, steps=cfg.steps,
-        lr=cfg.lr, gram_override=builder._train_gram, impl=cfg.train_impl,
-    )
-    builder.params = trained.params
-    p = builder.params
-    noise = jnp.exp(p.log_noise)
-    K = n_c
-    Xc = X_recon[:K]
-    # ---- the one-time factorization ----
-    if gram_backend == "pallas":
-        sq_cols = builder._ip("sq")
-        if gram_mode == "direct":
-            G_KK = G_KN = None
+        kernel, gram_mode, gram_backend = cfg.kernel, cfg.gram_mode, cfg.gram_backend
+        d = X_recon.shape[1]
+        if gram_mode == "nystrom_fitc":  # exact |x|^2 side-channel (32 bits/point)
+            wire += 32 * (X_recon.shape[0] - n_c)
+            payload += 32 * (X_recon.shape[0] - n_c)
+        builder = CenterGP(
+            kernel=kernel,
+            params=params or init_params(),
+            X_recon=X_recon,
+            y=y_all,
+            n_center=n_c,
+            wire_bits=wire,
+            gram_mode=gram_mode,
+            sq_norms=sq_norms,
+            gram_backend=gram_backend,
+            wire=wire_state,
+            block_order=tuple(order),
+            block_lengths=shards.lengths,
+            pack_bits=row_bits(cfg.bits_per_sample, d, cfg.max_bits),
+            payload_bits=payload,
+            integrity_bits=integrity,
+        )
+    with span("fit.train"):
+        trained = train_gp(
+            X_recon, y_all, kernel=kernel, params=builder.params, steps=cfg.steps,
+            lr=cfg.lr, gram_override=builder._train_gram, impl=cfg.train_impl,
+        )
+        builder.params = trained.params
+        p = builder.params
+        noise = jnp.exp(p.log_noise)
+    with span("fit.factors"):
+        K = n_c
+        Xc = X_recon[:K]
+        # ---- the one-time factorization ----
+        if gram_backend == "pallas":
+            sq_cols = builder._ip("sq")
+            if gram_mode == "direct":
+                G_KK = G_KN = None
+            else:
+                ip_KN = builder._ip("KN")
+                G_KK = kernel_from_inner(
+                    kernel, p, ip_KN[:, :K], sq_cols[:K], sq_cols[:K]
+                )
+                G_KN = kernel_from_inner(kernel, p, ip_KN, sq_cols[:K], sq_cols)
         else:
-            ip_KN = builder._ip("KN")
-            G_KK = kernel_from_inner(kernel, p, ip_KN[:, :K], sq_cols[:K], sq_cols[:K])
-            G_KN = kernel_from_inner(kernel, p, ip_KN, sq_cols[:K], sq_cols)
-    else:
-        sq_cols = jnp.sum(X_recon**2, axis=-1)
-        if gram_mode == "direct":
-            G_KK = G_KN = None
+            sq_cols = jnp.sum(X_recon**2, axis=-1)
+            if gram_mode == "direct":
+                G_KK = G_KN = None
+            else:
+                k = gram_fn(kernel)
+                G_KK = k(p, Xc)
+                G_KN = k(p, Xc, X_recon)
+
+        if gram_mode == "nystrom":
+            factors = nystrom_factors(G_KK, G_KN, y_all, noise)
+            if getattr(cfg, "serve_epilogue", "fused") == "fused":
+                factors.update(nystrom_serve_cache(factors))
+        elif gram_mode == "nystrom_fitc":
+            G = nystrom_complete(G_KK, G_KN, exact_diag=builder._exact_diag(p))
+            factors = posterior_factors(G, y_all, noise)
+            # FITC-consistent test map Q_*N = G_*K G_KK^{-1} G_KN needs (L_KK, W)
+            L_KK = chol_jittered(G_KK, DEFAULT_JITTER * jnp.trace(G_KK) / K)
+            factors["L_KK"] = L_KK
+            factors["W"] = jax.scipy.linalg.solve_triangular(L_KK, G_KN, lower=True)
+        elif gram_mode == "direct":
+            factors = posterior_factors(builder._gram(p), y_all, noise)
         else:
-            k = gram_fn(kernel)
-            G_KK = k(p, Xc)
-            G_KN = k(p, Xc, X_recon)
+            raise ValueError(f"unknown gram mode {gram_mode!r}")
 
-    if gram_mode == "nystrom":
-        factors = nystrom_factors(G_KK, G_KN, y_all, noise)
-        if getattr(cfg, "serve_epilogue", "fused") == "fused":
-            factors.update(nystrom_serve_cache(factors))
-    elif gram_mode == "nystrom_fitc":
-        G = nystrom_complete(G_KK, G_KN, exact_diag=builder._exact_diag(p))
-        factors = posterior_factors(G, y_all, noise)
-        # FITC-consistent test map Q_*N = G_*K G_KK^{-1} G_KN needs (L_KK, W)
-        L_KK = chol_jittered(G_KK, DEFAULT_JITTER * jnp.trace(G_KK) / K)
-        factors["L_KK"] = L_KK
-        factors["W"] = jax.scipy.linalg.solve_triangular(L_KK, G_KN, lower=True)
-    elif gram_mode == "direct":
-        factors = posterior_factors(builder._gram(p), y_all, noise)
-    else:
-        raise ValueError(f"unknown gram mode {gram_mode!r}")
-
-    data = {
-        "Xc": Xc, "X_recon": X_recon, "sq_cols": sq_cols,
-        "sq_exact": sq_norms,
-        # column-validity mask of the streaming buffers: all-live at fit time
-        # (SE kernels do not vanish at padded zero points, so the padded
-        # predict/update programs multiply this in)
-        "valid": jnp.ones_like(y_all),
-    }
-    data.update(extras)
-    return FittedProtocol(
-        params=p,
-        y=y_all,
-        factors=factors,
-        data=data,
-        wire=wire_state,
-        stream=StreamState.make(
-            shards.lengths, y_all.shape[0], int(wire), int(payload),
-            int(integrity), int(rows_demoted),
-        ),
-        protocol="center",
-        kernel=kernel,
-        gram_mode=gram_mode,
-        fuse="",
-        gram_backend=gram_backend,
-        n_center=K,
-        fit_lengths=shards.lengths,
-        block_order=tuple(order),
-        bits_per_sample=cfg.bits_per_sample,
-        max_bits=cfg.max_bits,
-        impl=cfg.impl,
-        scheme=cfg.scheme,
-        config=cfg,
-    )
+        data = {
+            "Xc": Xc, "X_recon": X_recon, "sq_cols": sq_cols,
+            "sq_exact": sq_norms,
+            # column-validity mask of the streaming buffers: all-live at fit time
+            # (SE kernels do not vanish at padded zero points, so the padded
+            # predict/update programs multiply this in)
+            "valid": jnp.ones_like(y_all),
+        }
+        data.update(extras)
+        return FittedProtocol(
+            params=p,
+            y=y_all,
+            factors=factors,
+            data=data,
+            wire=wire_state,
+            stream=StreamState.make(
+                shards.lengths, y_all.shape[0], int(wire), int(payload),
+                int(integrity), int(rows_demoted),
+            ),
+            protocol="center",
+            kernel=kernel,
+            gram_mode=gram_mode,
+            fuse="",
+            gram_backend=gram_backend,
+            n_center=K,
+            fit_lengths=shards.lengths,
+            block_order=tuple(order),
+            bits_per_sample=cfg.bits_per_sample,
+            max_bits=cfg.max_bits,
+            impl=cfg.impl,
+            scheme=cfg.scheme,
+            config=cfg,
+        )
 
 
 def _predict_center(art: FittedProtocol, X_star, sq_star, g_ss, noise, avail=None):

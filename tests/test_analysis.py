@@ -335,6 +335,17 @@ def test_lint_trace_counter_encapsulation():
     assert _rules(src, "src/repro/analysis/foo.py") == []
 
 
+def test_lint_span_funnel():
+    src = ("import jax\ndef f():\n"
+           "    with jax.profiler.TraceAnnotation('x'):\n        pass\n")
+    assert _rules(src, "src/repro/core/foo.py") == ["span-funnel"]
+    assert _rules(src, "src/repro/spans.py") == []
+    deco = "import jax\n@jax.profiler.annotate_function\ndef f():\n    pass\n"
+    assert _rules(deco, "src/repro/core/foo.py") == ["span-funnel"]
+    imp = "from jax.profiler import TraceAnnotation\n"
+    assert _rules(imp, "src/repro/launch/foo.py") == ["span-funnel"]
+
+
 def test_lint_rule_table_is_live():
     assert len(RULES) >= 6  # the acceptance floor: at least 6 active rules
 
