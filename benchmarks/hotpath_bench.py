@@ -42,50 +42,6 @@ def _problem(n, d, m, seed=0):
     return X, y, jnp.asarray(Xt), parts
 
 
-def _warm_train_dispatch(X, y, steps: int, lr: float = 0.05):
-    """Warm-cache dispatch-overhead measurement: train_gp's OWN Adam step
-    (via gp.make_adam_step, so the benchmark always times the shipped update
-    rule), but with the jitted programs built ONCE and reused across timed
-    calls — train_gp builds fresh closures per call, so timing it always
-    includes trace+compile.  Loop issues ``steps`` cached dispatches; scan
-    issues one."""
-    from repro.core.gp import gram_fn, init_params, make_adam_step, nlml_from_gram
-
-    X, y = jnp.asarray(X), jnp.asarray(y)
-    k = gram_fn("se")
-
-    def loss(p):
-        return nlml_from_gram(k(p, X), y, jnp.exp(p.log_noise))
-
-    step = make_adam_step(loss, lr)
-    jstep = jax.jit(step)
-
-    @jax.jit
-    def scan_run(p, m, v):
-        def body(carry, i):
-            return step(i, *carry), None
-
-        (p, m, v), _ = jax.lax.scan(body, (p, m, v), jnp.arange(steps, dtype=jnp.float32))
-        return p
-
-    p0 = init_params()
-    m0 = jax.tree.map(jnp.zeros_like, p0)
-    v0 = jax.tree.map(jnp.zeros_like, p0)
-
-    def run_loop():
-        p, m, v = p0, m0, v0
-        for i in range(steps):
-            p, m, v = jstep(jnp.float32(i), p, m, v)
-        return jax.block_until_ready(p)
-
-    def run_scan():
-        return jax.block_until_ready(scan_run(p0, m0, v0))
-
-    _, us_loop = timed(run_loop)  # timed() warms once -> repeats hit the cache
-    _, us_scan = timed(run_scan)
-    return us_loop, us_scan
-
-
 def main(quick: bool = True):
     from repro.core import train_gp, broadcast_gp
     from repro.core.distributed_gp import pad_parts, _run_wire_protocol
@@ -98,17 +54,18 @@ def main(quick: bool = True):
     X, y, Xt, parts = _problem(n, d, m)
 
     # ---- train_gp: per-step dispatch loop vs one scanned program ----
-    # Cold rows: a fresh train_gp call re-traces + re-compiles (what a fresh
-    # experiment pays).  Block on the returned params so async device
-    # execution is inside the measured window.
-    _, us_loop = timed(
-        lambda: jax.block_until_ready(train_gp(X, y, steps=steps, impl="loop").params),
-        repeats=1,
-    )
-    _, us_scan = timed(
-        lambda: jax.block_until_ready(train_gp(X, y, steps=steps, impl="scan").params),
-        repeats=1,
-    )
+    # Cold rows: the in-memory caches cleared before each call, so it
+    # re-traces + re-compiles (what a fresh process pays); warm rows reuse
+    # the programs train_gp keeps per shape.  Block on the returned params
+    # so async device execution is inside the measured window.
+    def train(impl, cold):
+        if cold:
+            jax.clear_caches()
+        return jax.block_until_ready(
+            train_gp(X, y, steps=steps, impl=impl).params)
+
+    _, us_loop = timed(train, "loop", True, repeats=1)
+    _, us_scan = timed(train, "scan", True, repeats=1)
     emit("hotpath/train_gp_loop", us_loop, host_dispatches=steps, includes_compile=1)
     emit(
         "hotpath/train_gp_scan",
@@ -118,7 +75,8 @@ def main(quick: bool = True):
         speedup=us_loop / us_scan,
         includes_compile=1,
     )
-    us_loop_w, us_scan_w = _warm_train_dispatch(X, y, steps)
+    _, us_loop_w = timed(train, "loop", False)  # timed() warms once
+    _, us_scan_w = timed(train, "scan", False)
     emit("hotpath/train_gp_loop_warm", us_loop_w, host_dispatches=steps)
     emit(
         "hotpath/train_gp_scan_warm",

@@ -21,6 +21,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..spans import span
 from .linalg_safe import DEFAULT_JITTER, chol_jittered, chol_safe
 from .nystrom import nystrom_nlml
 from .registry import KERNELS, KernelSpec, register_kernel
@@ -38,6 +39,9 @@ __all__ = [
     "nlml_from_gram",
     "GPModel",
     "make_adam_step",
+    "nystrom_from_inner",
+    "train_scan",
+    "train_trace_count",
     "train_gp",
 ]
 
@@ -207,7 +211,7 @@ class GPModel:
 
     kernel: str
     params: GPParams
-    X: jnp.ndarray
+    X: jnp.ndarray | None  # None where the caller trained on a gram hook alone
     y: jnp.ndarray
     gram_backend: str = "xla"
 
@@ -228,9 +232,7 @@ class GPModel:
 def make_adam_step(loss: Callable, lr: float) -> Callable:
     """One Adam update ``step(i, params, m, v) -> (params, m, v)`` for the
     given scalar loss — minimal inline Adam (repro.optim is for the NN stack;
-    keep core standalone).  Shared by train_gp and the warm-dispatch rows of
-    benchmarks/hotpath_bench.py so the benchmark always times the shipped
-    update rule."""
+    keep core standalone).  Shared by both of train_gp's programs."""
     b1, b2, eps = 0.9, 0.999, 1e-8
 
     def step(i, p, m, v):
@@ -246,6 +248,77 @@ def make_adam_step(loss: Callable, lr: float) -> Callable:
     return step
 
 
+# Incremented INSIDE the traced bodies of the training programs, so it counts
+# traces, not calls: fits of same-shaped data leave it flat.
+_TRAIN_TRACES = [0]
+
+
+def train_trace_count() -> int:
+    """How many times this process has traced a training program
+    (:func:`train_scan`, or the ``impl="loop"`` step) — a fit of data
+    shaped like an earlier fit's leaves it unchanged."""
+    return _TRAIN_TRACES[0]
+
+
+def nystrom_from_inner(p: GPParams, operands, kernel: str):
+    """The Nyström gram hook: the pair ``(G_KK, G_KN)`` from the inner
+    products ``ip_KK`` (K, K) and ``ip_KN`` (K, N) and the squared norms
+    ``sq_K`` (K,) and ``sq_N`` (N,) held in ``operands``."""
+    sq_K = operands["sq_K"]
+    return (kernel_from_inner(kernel, p, operands["ip_KK"], sq_K, sq_K),
+            kernel_from_inner(kernel, p, operands["ip_KN"], sq_K, operands["sq_N"]))
+
+
+def _dense_xla(p: GPParams, operands, kernel: str):
+    return gram_fn(kernel)(p, operands["X"])
+
+
+def _dense_pallas(p: GPParams, operands, kernel: str):
+    return gram_fn(kernel, "pallas")(p, operands["X"])
+
+
+# the gram hook of a fit with none given: the kernel on ``operands["X"]``
+_DENSE_GRAMS = {"xla": _dense_xla, "pallas": _dense_pallas}
+
+
+def _train_loss(p: GPParams, operands, kernel: str, gram: Callable):
+    """Negative log marginal likelihood of ``operands["y"]`` under the gram
+    ``gram(p, operands, kernel)``; a Nyström pair ``(G_KK, G_KN)`` trains on
+    the completed gram (eq. 61) without forming the N x N matrix."""
+    G = gram(p, operands, kernel)
+    noise = jnp.exp(p.log_noise)
+    if isinstance(G, tuple):
+        return nystrom_nlml(*G, operands["y"], noise)
+    return nlml_from_gram(G, operands["y"], noise)
+
+
+@functools.partial(jax.jit, static_argnames=("kernel", "gram", "steps", "lr"))
+def train_scan(p, m, v, operands, *, kernel: str, gram: Callable, steps: int,
+               lr: float):
+    """``steps`` Adam updates of the hyperparameters as ONE program (a
+    ``lax.scan``); the data are arguments, so every fit of same-shaped data
+    under the same static configuration reuses the compiled program."""
+    _TRAIN_TRACES[0] += 1  # runs at trace time only
+    step = make_adam_step(
+        lambda q: _train_loss(q, operands, kernel, gram), lr)
+
+    def body(carry, i):
+        return step(i, *carry), None
+
+    (p, m, v), _ = jax.lax.scan(
+        body, (p, m, v), jnp.arange(steps, dtype=jnp.float32))
+    return p, m, v
+
+
+@functools.partial(jax.jit, static_argnames=("kernel", "gram", "lr"))
+def _train_step(i, p, m, v, operands, *, kernel: str, gram: Callable,
+                lr: float):
+    """One Adam update: the ``impl="loop"`` baseline's program."""
+    _TRAIN_TRACES[0] += 1  # runs at trace time only
+    return make_adam_step(
+        lambda q: _train_loss(q, operands, kernel, gram), lr)(i, p, m, v)
+
+
 def train_gp(
     X,
     y,
@@ -253,60 +326,62 @@ def train_gp(
     params: GPParams | None = None,
     steps: int = 200,
     lr: float = 0.05,
-    gram_override: Callable | None = None,
+    gram: Callable | None = None,
+    operands: dict | None = None,
     impl: str = "scan",
     gram_backend: str = "xla",
 ) -> GPModel:
     """Maximize marginal likelihood with Adam.
 
-    ``gram_override(params) -> G`` lets distributed variants train on an
-    externally assembled (e.g. quantized) gram matrix.  An override that
-    returns the Nyström pair ``(G_KK, G_KN)`` trains on the completed gram
-    (eq. 61) through :func:`~repro.core.nystrom.nystrom_nlml`, never forming
-    the N x N matrix.
+    With no ``gram`` the loss is the GP's on the points ``X`` (through
+    ``gram_backend``'s inner products: ``"pallas"`` uses the tiled kernel,
+    differentiable via its custom VJP).  Distributed variants train on a gram
+    they assemble themselves (e.g. from quantized inner products): ``gram``
+    is a pure, module-level function ``gram(p, operands, kernel) -> G`` and
+    ``operands`` a dict of the arrays it reads (``y`` joins it under
+    ``"y"``).  A ``gram`` that returns the Nyström pair ``(G_KK, G_KN)``
+    trains on the completed gram (eq. 61) through
+    :func:`~repro.core.nystrom.nystrom_nlml`, never forming the N x N matrix
+    (:func:`nystrom_from_inner` is that hook over inner products).
+
+    The training program is jitted once per shape: ``gram``, ``kernel``,
+    ``steps`` and ``lr`` are its static arguments and the operands its
+    arguments, so a later fit of same-shaped data, new data included, reuses
+    it.  A closure over arrays, or any function object made anew per call,
+    would be a new static argument each time and rebuild the program in
+    every fit, with the data baked in as constants: so ``gram`` must not be
+    one.  :func:`train_trace_count` counts the builds; after each call the
+    marker span ``repro.fit.train.program`` records, as ``built``, how many
+    this call made.
 
     ``impl="scan"`` (default) runs the whole optimizer loop as ONE compiled
-    ``jax.lax.scan`` program — one trace, one device dispatch for all
-    ``steps``.  ``impl="loop"`` keeps the legacy per-step jit dispatch
+    ``jax.lax.scan`` program (:func:`train_scan`) — one dispatch for all
+    ``steps``.  ``impl="loop"`` dispatches one jitted step per iteration
     (O(steps) host round-trips); it exists as the baseline for
-    benchmarks/hotpath_bench.py.
-
-    ``gram_backend="pallas"`` computes the training gram's inner products
-    with the tiled Pallas kernel (differentiable via its custom VJP)."""
-    X = jnp.asarray(X)
+    benchmarks/hotpath_bench.py."""
+    if gram is None:
+        if gram_backend not in _DENSE_GRAMS:
+            raise ValueError(f"unknown gram backend {gram_backend!r}")
+        X = jnp.asarray(X)
+        gram, operands = _DENSE_GRAMS[gram_backend], {"X": X}
     y = jnp.asarray(y)
-    params = params or init_params()
-    k = gram_fn(kernel, gram_backend)
-
-    def loss(p):
-        G = gram_override(p) if gram_override is not None else k(p, X)
-        if isinstance(G, tuple):  # the Nyström pair (G_KK, G_KN)
-            return nystrom_nlml(*G, y, jnp.exp(p.log_noise))
-        return nlml_from_gram(G, y, jnp.exp(p.log_noise))
-
+    operands = dict(operands, y=y)
     if impl not in ("scan", "loop"):
         raise ValueError(f"unknown train impl {impl!r}")
-    step = make_adam_step(loss, lr)
+    params = params or init_params()
     m = jax.tree.map(jnp.zeros_like, params)
     v = jax.tree.map(jnp.zeros_like, params)
+    static = dict(kernel=kernel, gram=gram, lr=lr)
 
-    if steps == 0:
-        pass  # nothing to train: no optimizer program is built or compiled
-    elif impl == "loop":
-        jstep = jax.jit(step)
-        for i in range(steps):
-            params, m, v = jstep(jnp.float32(i), params, m, v)
-    else:
-
-        @jax.jit
-        def train_scan(p, m, v):
-            def body(carry, i):
-                return step(i, *carry), None
-
-            (p, m, v), _ = jax.lax.scan(
-                body, (p, m, v), jnp.arange(steps, dtype=jnp.float32)
-            )
-            return p, m, v
-
-        params, m, v = train_scan(params, m, v)
+    if steps:  # with none, no optimizer program is built or run
+        built = _TRAIN_TRACES[0]
+        if impl == "loop":
+            for i in range(steps):
+                params, m, v = _train_step(jnp.float32(i), params, m, v,
+                                           operands, **static)
+        else:
+            params, m, v = train_scan(params, m, v, operands, steps=steps,
+                                      **static)
+        with span("fit.train.program", built=_TRAIN_TRACES[0] - built):
+            pass
     return GPModel(kernel=kernel, params=params, X=X, y=y, gram_backend=gram_backend)

@@ -40,7 +40,7 @@ def nystrom_complete(G_KK, G_KN, exact_diag=None):
     G_KK: (K, K) exact; G_KN: (K, N) first K rows (incl. the K x K block).
     exact_diag: optional (N,) true diagonal to pin (FITC correction)."""
     K = G_KK.shape[0]
-    # differentiated (training-loss gram_override path): one-shot jitter —
+    # differentiated (a training gram hook): one-shot jitter —
     # lax.while_loop escalation has no reverse-mode rule
     L = chol_jittered(G_KK, DEFAULT_JITTER * jnp.trace(G_KK) / K)
     W = jax.scipy.linalg.solve_triangular(L, G_KN, lower=True)  # (K, N)

@@ -21,6 +21,7 @@ from ..gp import (
     GPParams,
     gram_fn,
     kernel_from_inner,
+    nystrom_from_inner,
     posterior_factors,
     posterior_apply,
     posterior_from_gram,
@@ -154,21 +155,16 @@ def fit_broadcast_host(parts, cfg, params=None) -> HostBroadcastGP:
         decoded.append(sch.decode(sch.encode(Xj)))
         wire += sch.wire_bits(Xj.shape[0]) + sch.side_info_bits(Xj.shape[1])
 
-    k = gram_fn(cfg.kernel)
-
     # train shared hypers at machine 0 on its own completed gram
-    blocks0 = [parts[0][0]] + [decoded[j] for j in range(1, m)]
-    X0 = jnp.concatenate(blocks0, axis=0)
+    Xc = jnp.asarray(parts[0][0], jnp.float32)
+    X0 = jnp.concatenate([Xc] + [decoded[j] for j in range(1, m)], axis=0)
     y0 = jnp.concatenate([yj for _, yj in parts], axis=0)
-    nc0 = parts[0][0].shape[0]
-
-    def gram0(p):  # the Nyström pair: train_gp completes it implicitly
-        Xc = X0[:nc0]
-        return k(p, Xc), k(p, Xc, X0)
-
+    sq_N = jnp.sum(X0**2, -1)
+    operands = {"ip_KK": Xc @ Xc.T, "ip_KN": Xc @ X0.T,
+                "sq_K": sq_N[: Xc.shape[0]], "sq_N": sq_N}
     trained = train_gp(
-        X0, y0, kernel=cfg.kernel, params=params, steps=cfg.steps, lr=cfg.lr,
-        gram_override=gram0, impl=cfg.train_impl,
+        None, y0, kernel=cfg.kernel, params=params, steps=cfg.steps, lr=cfg.lr,
+        gram=nystrom_from_inner, operands=operands, impl=cfg.train_impl,
     )
     from ...comm.accounting import integrity_bits_formula, payload_bits_formula
 
@@ -219,6 +215,41 @@ def _train_inner_products(
     A = jnp.einsum("ind,imd->inm", X, X)
     B = jnp.einsum("jnd,imd->jinm", wire.decoded, X)
     return A, B
+
+
+def _operands0(ip_own, ip_peers, sq_own, sq_dec, y, lengths):
+    """Machine 0's training operands, unpadded: its exact block as the
+    Nyström centers (``ip_KK``, ``sq_K``), its columns every machine's block
+    in machine order, its own exact and the others' reconstructions
+    (``ip_KN``, ``sq_N``), and every machine's targets (``y``).  ``ip_own``
+    (n_pad, n_pad) holds machine 0's products with itself, ``ip_peers``
+    (m, n_pad, n_pad) each machine's reconstruction against machine 0's
+    exact points, ``lengths`` the machines' row counts."""
+    n0, m = lengths[0], len(lengths)
+    ip_KK = ip_own[:n0, :n0]
+    ip_KN = jnp.concatenate(
+        [ip_KK] + [ip_peers[j, : lengths[j], :n0].T for j in range(1, m)], axis=1
+    )
+    sq_K = sq_own[:n0]
+    sq_N = jnp.concatenate([sq_K] + [sq_dec[j, : lengths[j]] for j in range(1, m)])
+    y0 = jnp.concatenate([y[j, : lengths[j]] for j in range(m)])
+    return {"ip_KK": ip_KK, "ip_KN": ip_KN, "sq_K": sq_K, "sq_N": sq_N, "y": y0}
+
+
+@partial(jax.jit, static_argnames="lengths")
+def _train_operands0(A, B, sq_exact, sq_dec, y, lengths):
+    """:func:`_operands0` from the batched tensors of
+    :func:`_train_inner_products`, as one program per shard layout."""
+    return _operands0(A[0], B[:, 0], sq_exact[0], sq_dec, y, lengths)
+
+
+@partial(jax.jit, static_argnames="lengths")
+def _mesh_train_operands0(X, decoded, sq_exact, sq_dec, y, lengths):
+    """:func:`_operands0` from the points: machine 0's exact block against
+    itself and every machine's reconstruction."""
+    X0 = X[0]
+    return _operands0(X0 @ X0.T, jnp.einsum("jnd,md->jnm", decoded, X0),
+                      sq_exact[0], sq_dec, y, lengths)
 
 
 def _star_exact_products(Xs, X_star, backend: str):
@@ -359,40 +390,22 @@ def _fit_broadcast(parts, cfg, params=None) -> FittedProtocol:
 
     with span("fit.train"):
         # ---- train shared hypers at machine 0 on its completed Nyström gram ----
-        # (unpadded slices; the inner products are param-independent constants, so
-        # the 150-step scan only re-does the cheap kernel map + Cholesky)
-        L = shards.lengths
-        n0 = L[0]
+        # (the inner products are param-independent arguments of the training
+        # program, so its steps only re-do the cheap kernel map + Cholesky)
         if cfg.impl == "mesh":
-            # machine-0-local training inputs, straight from the wire output (the
-            # batched A/B tensors below exist only to vmap the m simulated views)
-            X0s = jnp.asarray(shards.X[0, :n0], jnp.float32)
-            ip_KK0 = X0s @ X0s.T
-            X_cols0 = jnp.concatenate(
-                [X0s] + [wire_state.decoded[j, : L[j]] for j in range(1, m)], axis=0
+            # machine-0-local products, straight from the wire output (the
+            # batched A/B tensors exist only to vmap the m simulated views)
+            operands = _mesh_train_operands0(
+                shards.X, wire_state.decoded, sq_exact, sq_dec, shards.y,
+                lengths=shards.lengths,
             )
-            ip_KN0 = X0s @ X_cols0.T
         else:
-            ip_KK0 = A[0][:n0, :n0]
-            ip_KN0 = jnp.concatenate(
-                [ip_KK0] + [B[j, 0][: L[j], :n0].T for j in range(1, m)], axis=1
+            operands = _train_operands0(
+                A, B, sq_exact, sq_dec, shards.y, lengths=shards.lengths
             )
-        sq0 = sq_exact[0][:n0]
-        sq_cols0 = jnp.concatenate([sq0] + [sq_dec[j][: L[j]] for j in range(1, m)])
-        y0 = jnp.concatenate([shards.y[j, : L[j]] for j in range(m)], axis=0)
-        X0 = jnp.concatenate(
-            [shards.X[0, :n0]] + [wire_state.decoded[j, : L[j]] for j in range(1, m)],
-            axis=0,
-        )
-
-        def gram0(p):  # the Nyström pair: train_gp completes it implicitly
-            G_KK = kernel_from_inner(kernel, p, ip_KK0, sq0, sq0)
-            G_KN = kernel_from_inner(kernel, p, ip_KN0, sq0, sq_cols0)
-            return G_KK, G_KN
-
         trained = train_gp(
-            X0, y0, kernel=kernel, params=params, steps=cfg.steps, lr=cfg.lr,
-            gram_override=gram0, impl=cfg.train_impl,
+            None, operands["y"], kernel=kernel, params=params, steps=cfg.steps, lr=cfg.lr,
+            gram=nystrom_from_inner, operands=operands, impl=cfg.train_impl,
         )
         p = trained.params
         noise = jnp.exp(p.log_noise)

@@ -23,6 +23,7 @@ from ..gp import (
     init_params,
     gram_fn,
     kernel_from_inner,
+    nystrom_from_inner,
     prior_diag,
     posterior_factors,
     posterior_apply,
@@ -197,15 +198,11 @@ class CenterGP:
     _ip_cache: dict = dataclasses.field(default_factory=dict, repr=False)
 
     def __post_init__(self):
-        if self.gram_backend == "pallas":
-            if self.wire is None:
-                raise ValueError(
-                    'gram_backend="pallas" requires the batched wire protocol '
-                    "(int codes) — use impl=\"batched\""
-                )
-            # materialize the inner-product cache NOW, outside any jit trace:
-            # a cache miss inside train_gp's scan would store a leaked tracer
-            self.warm_ip()
+        if self.gram_backend == "pallas" and self.wire is None:
+            raise ValueError(
+                'gram_backend="pallas" requires the batched wire protocol '
+                "(int codes) — use impl=\"batched\""
+            )
 
     def _exact_diag(self, params):
         """k(x_i, x_i) from the EXACT squared norms the machines shipped."""
@@ -221,39 +218,39 @@ class CenterGP:
         )
 
     def _ip(self, key: str):
-        """Cached param-independent inner products (pallas backend): computed
-        once with the kernels, then reused as constants by every training step
+        """Cached param-independent inner products, computed once (with the
+        kernels under the pallas backend), then passed to every training step
         and prediction."""
         if key not in self._ip_cache:
-            Xc = self.X_recon[: self.n_center]
-            if key == "KN":
-                self._ip_cache[key] = self._ip_rows(Xc).T  # (n_c, N)
-            elif key == "NN":
-                self._ip_cache[key] = self._ip_rows(self.X_recon)  # (N, N)
-            elif key == "sq":
-                self._ip_cache[key] = jnp.sum(self.X_recon**2, axis=-1)
+            X, Xc = self.X_recon, self.X_recon[: self.n_center]
+            if key == "sq":
+                ip = jnp.sum(X**2, axis=-1)
+            elif self.gram_backend == "pallas":
+                ip = self._ip_rows(Xc).T if key == "KN" else self._ip_rows(X)
+            else:
+                ip = (Xc if key == "KN" else X) @ X.T
+            self._ip_cache[key] = ip  # KN (n_c, N), NN (N, N), sq (N,)
         return self._ip_cache[key]
 
-    def warm_ip(self):
-        """Materialize the inner-product cache eagerly (before train_gp's scan
-        traces _gram) so the Pallas kernels run once, not once per trace."""
-        if self.gram_backend != "pallas":
-            return self
-        self._ip("sq")
-        self._ip("NN" if self.gram_mode == "direct" else "KN")
-        return self
+    def train_operands(self) -> dict:
+        """What :data:`_TRAIN_GRAMS`' hook for this gram mode reads: the
+        cached inner products and squared norms, and the exact squared norms
+        of the FITC diagonal."""
+        sq = self._ip("sq")
+        if self.gram_mode == "direct":
+            return {"ip": self._ip("NN"), "sq": sq}
+        K, ip_KN = self.n_center, self._ip("KN")
+        ops = {"ip_KK": ip_KN[:, :K], "ip_KN": ip_KN, "sq_K": sq[:K], "sq_N": sq}
+        if self.gram_mode == "nystrom_fitc" and self.sq_norms is not None:
+            ops["sq_exact"] = self.sq_norms
+        return ops
 
     def _nystrom_blocks(self, params):
         """The completed gram's first K rows, (G_KK, G_KN)."""
-        K = self.n_center
         if self.gram_backend == "pallas":
-            sq, ip_KN = self._ip("sq"), self._ip("KN")
-            G_KK = kernel_from_inner(self.kernel, params, ip_KN[:, :K], sq[:K],
-                                     sq[:K])
-            return G_KK, kernel_from_inner(self.kernel, params, ip_KN, sq[:K],
-                                           sq)
+            return nystrom_from_inner(params, self.train_operands(), self.kernel)
         k = gram_fn(self.kernel)
-        Xc = self.X_recon[:K]
+        Xc = self.X_recon[: self.n_center]
         return k(params, Xc), k(params, Xc, self.X_recon)
 
     def _gram(self, params):
@@ -271,14 +268,6 @@ class CenterGP:
             # correction acts like per-point noise, taming the rank-K inverse)
             return nystrom_complete(G_KK, G_KN, exact_diag=self._exact_diag(params))
         return nystrom_complete(G_KK, G_KN)
-
-    def _train_gram(self, params):
-        """What ``train_gp`` trains on: the Nyström pair itself in
-        ``nystrom`` mode (woodbury NLML, no N x N matrix), else the dense
-        gram of :meth:`_gram`."""
-        if self.gram_mode == "nystrom":
-            return self._nystrom_blocks(params)
-        return self._gram(params)
 
     def predict(self, X_star, available=None):
         # ``available`` is accepted for surface parity with the fused-family
@@ -346,6 +335,33 @@ class CenterGP:
         return posterior_from_gram(G, G_sn, g_ss, self.y, noise)
 
 
+def _fitc_train_gram(p: GPParams, operands, kernel: str):
+    """The Nyström-completed gram, its diagonal pinned to the exact one
+    where the machines shipped their exact squared norms."""
+    G_KK, G_KN = nystrom_from_inner(p, operands, kernel)
+    exact = operands.get("sq_exact")
+    return nystrom_complete(
+        G_KK, G_KN,
+        exact_diag=None if exact is None else prior_diag(kernel, p, exact),
+    )
+
+
+def _direct_train_gram(p: GPParams, operands, kernel: str):
+    """Every block straight from the reconstructed points' inner products."""
+    sq = operands["sq"]
+    return kernel_from_inner(kernel, p, operands["ip"], sq, sq)
+
+
+# what ``train_gp`` trains a center fit on, by gram mode: the Nyström pair
+# itself in ``nystrom`` mode (woodbury NLML, no N x N matrix), else the dense
+# gram; each reads :meth:`CenterGP.train_operands`
+_TRAIN_GRAMS = {
+    "nystrom": nystrom_from_inner,
+    "nystrom_fitc": _fitc_train_gram,
+    "direct": _direct_train_gram,
+}
+
+
 def _check_center(cfg, parts):
     if not cfg.center < len(parts):
         raise ValueError(
@@ -393,11 +409,11 @@ def fit_center_host(parts, cfg, params: GPParams | None = None) -> CenterGP:
         payload_bits=payload,
         integrity_bits=integrity,
     )
-    trained = train_gp(
-        X_recon, y_all, kernel=cfg.kernel, params=model.params, steps=cfg.steps,
-        lr=cfg.lr, gram_override=model._train_gram, impl=cfg.train_impl,
-    )
-    model.params = trained.params
+    model.params = train_gp(
+        None, y_all, kernel=cfg.kernel, params=model.params, steps=cfg.steps,
+        lr=cfg.lr, gram=_TRAIN_GRAMS[cfg.gram_mode],
+        operands=model.train_operands(), impl=cfg.train_impl,
+    ).params
     return model
 
 
@@ -481,37 +497,21 @@ def _fit_center(parts, cfg, params: GPParams | None = None) -> FittedProtocol:
             payload_bits=payload,
             integrity_bits=integrity,
         )
+        operands = builder.train_operands()  # the inner products the wire gives
     with span("fit.train"):
-        trained = train_gp(
-            X_recon, y_all, kernel=kernel, params=builder.params, steps=cfg.steps,
-            lr=cfg.lr, gram_override=builder._train_gram, impl=cfg.train_impl,
-        )
-        builder.params = trained.params
-        p = builder.params
+        builder.params = p = train_gp(
+            None, y_all, kernel=kernel, params=builder.params, steps=cfg.steps,
+            lr=cfg.lr, gram=_TRAIN_GRAMS[gram_mode], operands=operands,
+            impl=cfg.train_impl,
+        ).params
         noise = jnp.exp(p.log_noise)
     with span("fit.factors"):
         K = n_c
         Xc = X_recon[:K]
-        # ---- the one-time factorization ----
-        if gram_backend == "pallas":
-            sq_cols = builder._ip("sq")
-            if gram_mode == "direct":
-                G_KK = G_KN = None
-            else:
-                ip_KN = builder._ip("KN")
-                G_KK = kernel_from_inner(
-                    kernel, p, ip_KN[:, :K], sq_cols[:K], sq_cols[:K]
-                )
-                G_KN = kernel_from_inner(kernel, p, ip_KN, sq_cols[:K], sq_cols)
-        else:
-            sq_cols = jnp.sum(X_recon**2, axis=-1)
-            if gram_mode == "direct":
-                G_KK = G_KN = None
-            else:
-                k = gram_fn(kernel)
-                G_KK = k(p, Xc)
-                G_KN = k(p, Xc, X_recon)
-
+        # ---- the one-time factorization, from the training's inner products ----
+        sq_cols = builder._ip("sq")
+        if gram_mode != "direct":
+            G_KK, G_KN = nystrom_from_inner(p, operands, kernel)
         if gram_mode == "nystrom":
             factors = nystrom_factors(G_KK, G_KN, y_all, noise)
             if getattr(cfg, "serve_epilogue", "fused") == "fused":

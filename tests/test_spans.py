@@ -12,6 +12,7 @@ from repro.core import DGPConfig, DistributedGP
 from repro.core.protocols import base
 
 PHASES = ("repro.fit.wire", "repro.fit.train", "repro.fit.factors")
+MARKER = "repro.fit.train.program"
 SMALL = dict(bits_per_sample=8, steps=8)
 
 
@@ -22,8 +23,9 @@ def _data(n=480, d=4, seed=0):
 
 
 def _read(trace_dir):
-    """([(name, start, end, fit id)] of the ``repro.*`` host events, by
-    start; the start of every call of the training scan's jitted program)."""
+    """([(name, start, end, fit id, built)] of the ``repro.*`` host events,
+    by start, ``built`` the training marker's stat; the start of every call
+    of the training scan's jitted program)."""
     from jax.profiler import ProfileData
 
     (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
@@ -32,8 +34,9 @@ def _read(trace_dir):
         for line in plane.lines:
             for e in line.events:
                 if e.name.startswith(spans.PREFIX):
+                    st = dict(e.stats)
                     out.append((e.name, e.start_ns, e.start_ns + e.duration_ns,
-                                dict(e.stats).get("fit")))
+                                st.get("fit"), st.get("built")))
                 elif e.name == "PjitFunction(train_scan)":
                     scans.append(e.start_ns)
     return sorted(out, key=lambda s: s[1]), scans
@@ -78,14 +81,29 @@ def test_one_root_per_fit_with_distinct_ids(traced):
 def test_phases_nest_in_their_root_in_order(traced):
     _, got, _ = traced
     roots = [s for s in got if s[0] == "repro.fit"]
-    for _, r0, r1, fit in roots:
+    for _, r0, r1, fit, _ in roots:
         inside = [s for s in got if s[0] != "repro.fit" and r0 <= s[1] < r1]
-        assert tuple(s[0] for s in inside) == PHASES
-        for name, s0, s1, sid in inside:
+        phases = [s for s in inside if s[0] in PHASES]
+        assert tuple(s[0] for s in phases) == PHASES
+        assert [s[0] for s in inside if s[0] not in PHASES] == [MARKER]
+        for name, s0, s1, sid, _ in inside:
             assert r0 <= s0 <= s1 <= r1, name
             assert sid == fit, name
-        for a, b in zip(inside, inside[1:]):
+        for a, b in zip(phases, phases[1:]):
             assert a[2] <= b[1], (a[0], b[0])  # no overlap
+
+
+def test_training_marker_says_whether_the_program_was_built(traced):
+    """The marker closes inside ``repro.fit.train`` after the training
+    program's call; the second fit, of the same data, builds nothing."""
+    _, got, _ = traced
+    train = [s for s in got if s[0] == "repro.fit.train"]
+    marks = [s for s in got if s[0] == MARKER]
+    assert len(marks) == len(train) == 2
+    for (_, t0, t1, *_), (_, m0, m1, *_) in zip(train, marks):
+        assert t0 <= m0 <= m1 <= t1
+    built = [b for *_, b in marks]
+    assert built[0] in (0, 1) and built[1] == 0
 
 
 def test_span_without_profiler_is_a_noop():
@@ -105,5 +123,5 @@ def test_training_scan_has_a_stable_program_name(traced):
     roots = [s for s in got if s[0] == "repro.fit"]
     train = [s for s in got if s[0] == "repro.fit.train"]
     assert len(train) == len(roots) == 2
-    for _, t0, t1, _ in train:  # retraced and dispatched in every fit
+    for _, t0, t1, *_ in train:  # dispatched in every fit
         assert any(t0 <= s < t1 for s in dispatched)
