@@ -33,8 +33,9 @@ SERVE_EPILOGUES = ("fused", "unfused")
 # capacity-padded factor arrays plus the stream/* leaves (per-machine counts,
 # occupied-column counter, device-resident ledgers) — v1-v4 load at exact
 # capacity and pad up on their first update(); 6 = fused-serve-epilogue
-# cache keys (factors/Ainv, factors/U, factors/walpha) on Nyström artifacts —
-# v1-v5 load fine and simply serve on the unfused path (the keys are absent)
+# cache keys (factors/Ainv, factors/walpha) on Nyström artifacts — v1-v5
+# load fine and simply serve on the unfused path (the keys are absent); v6
+# files written with the retired factors/U carry it unread
 ARTIFACT_FORMAT_VERSION = 6
 
 

@@ -25,6 +25,7 @@ __all__ = [
     "nystrom_factors",
     "nystrom_apply",
     "nystrom_serve_cache",
+    "nystrom_projector",
     "nystrom_apply_cached",
     "nystrom_kinv",
     "chol_update",
@@ -141,7 +142,6 @@ def nystrom_serve_cache(factors):
     streaming updates, so these need no ``streaming._GROWTH`` entries):
 
       Ainv   = L_KK^{-1}        (K, K)  explicit triangular inverse
-      U      = W W^T            (K, K)
       walpha = W alpha          (K,)
 
     With these, :func:`nystrom_apply_cached` serves a query batch with
@@ -154,7 +154,20 @@ def nystrom_serve_cache(factors):
     Ainv = jax.scipy.linalg.solve_triangular(
         L, jnp.eye(K, dtype=L.dtype), lower=True
     )
-    return {"Ainv": Ainv, "U": W @ W.T, "walpha": W @ alpha}
+    return {"Ainv": Ainv, "walpha": W @ alpha}
+
+
+def nystrom_projector(L_M, s2):
+    """The quad-form projector of the cached serve, P = (U - U M^{-1} U)/s2
+    with U = W W^T and M = s2 I + U = L_M L_M^T, computed from L_M alone as
+    P = I - s2 M^{-1} (equal: U M^{-1} U = U - s2 M^{-1} U), so the
+    artifact keeps no U.  The difference form subtracts two
+    matrices of the size of U's largest eigenvalue and divides by s2, so in
+    float32 it keeps little of P once that eigenvalue is many times s2: at
+    sarcos's 44,484 columns it moved served latent variances by a tenth of
+    the prior on a TPU v5e.  This form never exceeds the identity."""
+    eye = jnp.eye(L_M.shape[0], dtype=L_M.dtype)
+    return eye - s2 * jax.scipy.linalg.cho_solve((L_M, True), eye)
 
 
 def nystrom_apply_cached(factors, G_star_K, g_star_star, noise_var):
@@ -165,16 +178,15 @@ def nystrom_apply_cached(factors, G_star_K, g_star_star, noise_var):
 
       mean = G_*N alpha = B^T (W alpha)
       quad = diag(G_*N (Ghat + s2 I)^{-1} G_*N^T) = diag(B^T P B),
-      P    = (U - U M^{-1} U) / s2            (woodbury through L_M)
+      P    = (U - U M^{-1} U) / s2            (woodbury through L_M,
+                                               :func:`nystrom_projector`)
 
     — no per-column :func:`nystrom_kinv`, no O(N) operand anywhere."""
-    Ainv, U, Lm, walpha = (
-        factors["Ainv"], factors["U"], factors["L_M"], factors["walpha"],
-    )
+    Ainv, Lm, walpha = factors["Ainv"], factors["L_M"], factors["walpha"]
     s2 = noise_var + DEFAULT_JITTER
     B = Ainv @ G_star_K.T  # (K, t)
     mean = B.T @ walpha
-    P = (U - U @ jax.scipy.linalg.cho_solve((Lm, True), U)) / s2  # (K, K)
+    P = nystrom_projector(Lm, s2)  # (K, K)
     var = g_star_star - jnp.sum(B * (P @ B), axis=0)
     return mean, jnp.maximum(var, 1e-12)
 

@@ -447,7 +447,8 @@ def fit(
     protocol="center" (§5.1): every machine quantizes toward the center's
     covariance; the center Nyström-completes and holds one factor set.
     protocol="broadcast" (§5.2): every machine broadcasts once; m local
-    Nyström factor sets are built under one vmap and fused (``fuse``: a
+    Nyström factor sets are built, in groups of receivers sized to the
+    device's memory, and fused (``fuse``: a
     ``repro.core.registry.FUSIONS`` name — "kl" = eqs. 62-64 barycenter, or
     a PoE-family combiner).
     protocol="poe": the zero-rate baseline (``method``: poe/gpoe/bcm/rbcm);

@@ -32,6 +32,7 @@ from ..nystrom import (
     nystrom_factors,
     nystrom_apply,
     nystrom_serve_cache,
+    nystrom_projector,
     nystrom_apply_cached,
     nystrom_kinv,
     chol_update_rank,
@@ -185,36 +186,51 @@ def fit_broadcast_host(parts, cfg, params=None) -> HostBroadcastGP:
 # --------------------------------------------------------------------------
 
 
-def _train_inner_products(
-    shards: PaddedShards, wire: WireState, backend: str, pack_bits: int = 0
-):
-    """The query-independent inner-product tensors every machine view is
-    assembled from (computed ONCE at fit time):
-
-    A (m, n, n): exact own-block products Xs_i Xs_i^T
-    B (m, m, n, n): B[j, i] = X̂_j Xs_i^T (decoded j against exact i)
-
-    backend="pallas" computes A with the tiled gram kernel and B straight
-    from the PACKED wire words with the fused unpack+dequantize+gram kernel
-    (``pack_bits``: the static row bit budget of the packed plane)."""
-    X = shards.X
+def _receiver_products(X_recv, mask, wire: WireState, backend: str,
+                       pack_bits: int = 0):
+    """(m, k, n_pad, n_pad): out[j, r] = X̂_j Xs_r^T, every sender j's
+    reconstruction against the exact points of each of the k receivers
+    ``X_recv`` (k, n_pad, d) — the receivers' columns of the wire's inner
+    products.  backend="pallas" computes them straight from the PACKED wire
+    words with the fused unpack+dequantize+gram kernel (``pack_bits``: the
+    static row bit budget of the packed plane; ``mask`` (m, n_pad) zeroes the
+    senders' padded rows)."""
     if backend == "pallas":
-        from ...kernels.gram.ops import gram as gram_kernel
         from ...kernels.qgram.ops import qgram_packed
 
-        A = jax.vmap(lambda a: gram_kernel(a, a))(X)
-        proj = jnp.einsum("ind,jde->jine", X, wire.T_inv)  # (m_j, m_i, n, d)
-        B = jax.vmap(
+        proj = jnp.einsum("ind,jde->jine", X_recv, wire.T_inv)  # (m_j, k, n, d)
+        return jax.vmap(
             lambda w, r, t, mk, ys: jax.vmap(
                 lambda yy: qgram_packed(
                     w, r, t, yy, total_bits=pack_bits, mask=mk
                 )
             )(ys)
-        )(wire.codes, wire.rates, wire.scaled_cents, shards.mask, proj)
-        return A, B
-    A = jnp.einsum("ind,imd->inm", X, X)
-    B = jnp.einsum("jnd,imd->jinm", wire.decoded, X)
-    return A, B
+        )(wire.codes, wire.rates, wire.scaled_cents, mask, proj)
+    return jnp.einsum("jnd,imd->jinm", wire.decoded, X_recv)
+
+
+@partial(jax.jit, static_argnames=("backend", "pack_bits"))
+def _train_inner_products(X, mask, wire: WireState, backend: str,
+                          pack_bits: int = 0):
+    """The inner products a fit needs before its factor build, as one
+    program per shard layout:
+
+    A (m, n_pad, n_pad): exact own-block products Xs_i Xs_i^T
+    B0 (m, n_pad, n_pad): B0[j] = X̂_j Xs_0^T, machine 0's column of the
+      wire's inner products (:func:`_receiver_products`), all its training
+      needs
+
+    backend="pallas" computes A with the tiled gram kernel.  The other
+    receivers' columns are computed group by group inside the factor build
+    (:func:`broadcast_factor_group`), so no (m, m, n_pad, n_pad) tensor is
+    ever held."""
+    if backend == "pallas":
+        from ...kernels.gram.ops import gram as gram_kernel
+
+        A = jax.vmap(lambda a: gram_kernel(a, a))(X)
+    else:
+        A = jnp.einsum("ind,imd->inm", X, X)
+    return A, _receiver_products(X[:1], mask, wire, backend, pack_bits)[:, 0]
 
 
 def _operands0(ip_own, ip_peers, sq_own, sq_dec, y, lengths):
@@ -237,10 +253,11 @@ def _operands0(ip_own, ip_peers, sq_own, sq_dec, y, lengths):
 
 
 @partial(jax.jit, static_argnames="lengths")
-def _train_operands0(A, B, sq_exact, sq_dec, y, lengths):
-    """:func:`_operands0` from the batched tensors of
-    :func:`_train_inner_products`, as one program per shard layout."""
-    return _operands0(A[0], B[:, 0], sq_exact[0], sq_dec, y, lengths)
+def _train_operands0(A, B0, sq_exact, sq_dec, y, lengths):
+    """:func:`_operands0` from the products of :func:`_train_inner_products`
+    (the own-block products A (m, n_pad, n_pad), of which it reads machine
+    0's, and machine 0's column B0), as one program per shard layout."""
+    return _operands0(A[0], B0, sq_exact[0], sq_dec, y, lengths)
 
 
 @partial(jax.jit, static_argnames="lengths")
@@ -250,6 +267,146 @@ def _mesh_train_operands0(X, decoded, sq_exact, sq_dec, y, lengths):
     X0 = X[0]
     return _operands0(X0 @ X0.T, jnp.einsum("jnd,md->jnm", decoded, X0),
                       sq_exact[0], sq_dec, y, lengths)
+
+
+# --------------------------------------------------------------------------
+# the factor build (batched impl): receivers in groups sized to the device
+# --------------------------------------------------------------------------
+
+# float32 copies of one receiver's (n_pad, m*n_pad) view that the build
+# holds at once: its column of wire products (padded to the kernel's blocks,
+# then relaid out), its kernel columns G_KN, the blocked triangular solve's
+# W = L_KK^{-1} G_KN and W relaid out for the artifact.  The TPU v5e
+# compiler's own count at m=40, n_pad=1,113: 1.01-1.15 GB of temporaries per
+# receiver for groups of 1 to 5, 5.1-5.8 views.
+_VIEW_COPIES = 6
+# share of the device's memory left to what the process holds besides the
+# artifact and the build (the dataset, the wire state, the allocator's slack:
+# a program's temporaries are reserved in one piece)
+_HEADROOM = 0.15
+
+
+def _device_memory_limit():
+    """Bytes the default device may allocate, None where the backend does
+    not say (the CPU)."""
+    stats = jax.local_devices()[0].memory_stats()
+    return None if not stats else stats.get("bytes_limit")
+
+
+def factor_group_size(m: int, n_pad: int, bytes_limit=None) -> int:
+    """Receivers per group of the factor build: as many as fit in
+    ``bytes_limit`` beside the artifact, balanced so that the groups are as
+    even as the count allows (the last one may overlap the one before it).
+
+    The artifact holds, per receiver, ``W`` (n_pad, N = m*n_pad), three
+    (n_pad, n_pad) factors (``L_KK``, ``L_M``, ``Ainv``), ``alpha`` (N,) and
+    ``walpha`` (n_pad,); the own-block products A stay alive through the
+    build.  Each
+    receiver built at once adds ``_VIEW_COPIES`` float32 views of (n_pad, N).
+    No limit (None) gives one group of all m receivers."""
+    if bytes_limit is None:
+        return m
+    N = m * n_pad
+    resident = 4 * m * (n_pad * N + 4 * n_pad * n_pad + N + n_pad)
+    per_receiver = 4 * _VIEW_COPIES * n_pad * N
+    free = int(bytes_limit * (1.0 - _HEADROOM)) - resident
+    k = max(1, min(m, free // per_receiver))
+    groups = -(-m // k)
+    return -(-m // groups)
+
+
+def _build_group(start, p, A, X, mask, wire: WireState, sq_exact, sq_dec,
+                 y_flat, *, kernel: str, group: int, backend: str,
+                 pack_bits: int, serve_cache: bool):
+    """The Nyström factor sets of receivers ``start`` .. ``start+group-1``,
+    stacked: ``L_KK``, ``W``, ``L_M``, ``alpha`` and, with ``serve_cache``,
+    the fused serve operands.
+
+    Receiver i's view: its exact block as the Nyström centers, its columns
+    every machine's block in machine order, its own exact and the others'
+    reconstructions.  The group's columns of the wire's inner products are
+    computed here from the wire state (:func:`_receiver_products`)."""
+    m, n_pad, _ = X.shape
+    noise = jnp.exp(p.log_noise)
+    mask_flat = mask.reshape(-1)  # column layout is block j at slot j
+
+    def build(i, cols, ip_KK):
+        # cols (m, n_pad, n_pad): block j is X̂_j Xs_i^T; own block exact
+        mask_i = mask[i]
+        blocks = cols.transpose(0, 2, 1).at[i].set(ip_KK)
+        ip_KN = jnp.moveaxis(blocks, 0, 1).reshape(n_pad, m * n_pad)
+        sq_cols = sq_dec.at[i].set(sq_exact[i]).reshape(-1)
+        G_KK = _mask_gram(
+            kernel_from_inner(kernel, p, ip_KK, sq_exact[i], sq_exact[i]), mask_i
+        )
+        G_KN = kernel_from_inner(kernel, p, ip_KN, sq_exact[i], sq_cols) * (
+            mask_i[:, None] * mask_flat[None, :]
+        )
+        fac = nystrom_factors(G_KK, G_KN, y_flat, noise)
+        if serve_cache:
+            fac.update(nystrom_serve_cache(fac))
+        return fac
+
+    X_g = jax.lax.dynamic_slice_in_dim(X, start, group)
+    cols = _receiver_products(X_g, mask, wire, backend, pack_bits)
+    return jax.vmap(build, in_axes=(0, 1, 0))(
+        start + jnp.arange(group), cols,
+        jax.lax.dynamic_slice_in_dim(A, start, group),
+    )
+
+
+_BUILD_STATIC = ("kernel", "group", "backend", "pack_bits", "serve_cache")
+
+
+@partial(jax.jit, static_argnames=_BUILD_STATIC)
+def broadcast_factor_buffers(p, A, X, mask, wire: WireState, sq_exact, sq_dec,
+                             y_flat, *, kernel: str, group: int, backend: str,
+                             pack_bits: int, serve_cache: bool):
+    """The artifact's factor buffers for :func:`broadcast_factor_group` to
+    fill: zeros shaped as every receiver's factor set, stacked over the m
+    receivers."""
+    build = partial(_build_group, kernel=kernel, group=group, backend=backend,
+                    pack_bits=pack_bits, serve_cache=serve_cache)
+    shapes = jax.eval_shape(build, 0, p, A, X, mask, wire, sq_exact, sq_dec,
+                            y_flat)
+    return jax.tree_util.tree_map(
+        lambda s: jnp.zeros((X.shape[0],) + s.shape[1:], s.dtype), shapes
+    )
+
+
+@partial(jax.jit, static_argnames=_BUILD_STATIC, donate_argnums=0)
+def broadcast_factor_group(factors, start, p, A, X, mask, wire: WireState,
+                           sq_exact, sq_dec, y_flat, *, kernel: str,
+                           group: int, backend: str, pack_bits: int,
+                           serve_cache: bool):
+    """One group's factor sets (:func:`_build_group`), written into the
+    donated artifact ``factors`` at receiver ``start``, in place.  One
+    program per shard layout and group size: ``start`` is an argument."""
+    fresh = _build_group(start, p, A, X, mask, wire, sq_exact, sq_dec, y_flat,
+                         kernel=kernel, group=group, backend=backend,
+                         pack_bits=pack_bits, serve_cache=serve_cache)
+    return jax.tree_util.tree_map(
+        lambda F, f: jax.lax.dynamic_update_slice_in_dim(F, f, start, 0),
+        factors, fresh,
+    )
+
+
+def _build_factors(p, A, X, mask, wire: WireState, sq_exact, sq_dec, y_flat,
+                   *, group: int, **static):
+    """Every receiver's factor set, ``group`` receivers a call: the groups
+    start at 0, group, 2*group, ..., the last one shifted back to end at
+    receiver m-1 (it recomputes rows of the one before, identically).  So the
+    device holds the artifact and one group's views, never m² blocks or m
+    views at once."""
+    m = X.shape[0]
+    args = (p, A, X, mask, wire, sq_exact, sq_dec, y_flat)
+    factors = broadcast_factor_buffers(*args, group=group, **static)
+    for g in range(-(-m // group)):
+        with span("fit.factors.group"):
+            factors = broadcast_factor_group(
+                factors, min(g * group, m - group), *args, group=group, **static
+            )
+    return factors
 
 
 def _star_exact_products(Xs, X_star, backend: str):
@@ -319,9 +476,10 @@ def broadcast_gp(
 
     The default ``impl="batched"`` is a thin serving composition:
     ``fit(parts, R, protocol="broadcast", ...)`` builds the
-    :class:`~.base.FittedProtocol` artifact (every machine's scheme fit,
-    decode, and Nyström factorization under jax.vmap on padded shards — one
-    batched Cholesky for all m local predictives instead of m serial ones),
+    :class:`~.base.FittedProtocol` artifact (every machine's scheme fit and
+    decode under jax.vmap on padded shards, and its Nyström factorization
+    in groups of receivers under one vmap each — batched Choleskys instead
+    of m serial ones),
     and :func:`~.base.predict` serves X_star from the cached factors.  Call
     ``fit`` directly (or the ``DistributedGP`` facade) to keep the artifact
     and amortize the protocol over many query batches."""
@@ -385,8 +543,10 @@ def _fit_broadcast(parts, cfg, params=None) -> FittedProtocol:
 
         sq_exact = jnp.sum(shards.X**2, -1)  # (m, n)
         sq_dec = jnp.sum(wire_state.decoded**2, -1)
-        if cfg.impl != "mesh":  # the inner products the wire gives
-            A, B = _train_inner_products(shards, wire_state, gram_backend, pack_bits)
+        if cfg.impl != "mesh":  # own blocks and machine 0's wire column
+            A, B0 = _train_inner_products(
+                shards.X, shards.mask, wire_state, gram_backend, pack_bits
+            )
 
     with span("fit.train"):
         # ---- train shared hypers at machine 0 on its completed Nyström gram ----
@@ -401,17 +561,23 @@ def _fit_broadcast(parts, cfg, params=None) -> FittedProtocol:
             )
         else:
             operands = _train_operands0(
-                A, B, sq_exact, sq_dec, shards.y, lengths=shards.lengths
+                A, B0, sq_exact, sq_dec, shards.y, lengths=shards.lengths
             )
+            del B0
         trained = train_gp(
             None, operands["y"], kernel=kernel, params=params, steps=cfg.steps, lr=cfg.lr,
             gram=nystrom_from_inner, operands=operands, impl=cfg.train_impl,
         )
         p = trained.params
         noise = jnp.exp(p.log_noise)
+        del operands
 
-    with span("fit.factors"):
-        # ---- factorize every machine's local predictive under ONE vmap ----
+    stats = {}
+    if cfg.impl != "mesh" and gram_mode == "nystrom":
+        group = factor_group_size(m, shards.X.shape[1], _device_memory_limit())
+        stats = {"groups": -(-m // group), "receivers": group}
+    with span("fit.factors", **stats):
+        # ---- factorize every machine's local predictive ----
         mask_flat = shards.mask.reshape(-1)  # column layout is block j at slot j
         y_flat = (shards.y * shards.mask).reshape(-1)
 
@@ -443,30 +609,16 @@ def _fit_broadcast(parts, cfg, params=None) -> FittedProtocol:
             )
 
         if gram_mode == "nystrom":
-
-            def build(i):
-                mask_i = shards.mask[i]
-                # own (exact) block is the Nyström center; peers are reconstructions
-                ip_KK = A[i]
-                blocks = B[:, i].transpose(0, 2, 1)  # block j: Xs_i X̂_j^T (n, n)
-                blocks = blocks.at[i].set(ip_KK)  # own block exact
-                ip_KN = jnp.moveaxis(blocks, 0, 1).reshape(n_pad, m * n_pad)
-                sq_cols = sq_dec.at[i].set(sq_exact[i]).reshape(-1)
-                G_KK = _mask_gram(
-                    kernel_from_inner(kernel, p, ip_KK, sq_exact[i], sq_exact[i]),
-                    mask_i,
-                )
-                G_KN = kernel_from_inner(kernel, p, ip_KN, sq_exact[i], sq_cols) * (
-                    mask_i[:, None] * mask_flat[None, :]
-                )
-                fac = nystrom_factors(G_KK, G_KN, y_flat, noise)
-                if fused_serve:
-                    fac.update(nystrom_serve_cache(fac))
-                return fac
-
-            factors = jax.vmap(build)(jnp.arange(m))
+            factors = _build_factors(
+                p, A, shards.X, shards.mask, wire_state, sq_exact, sq_dec,
+                y_flat, kernel=kernel, group=group, backend=gram_backend,
+                pack_bits=pack_bits, serve_cache=fused_serve,
+            )
         elif gram_mode == "direct":
             D = _decoded_inner_products(shards, wire_state, gram_backend, pack_bits)
+            B = _receiver_products(
+                shards.X, shards.mask, wire_state, gram_backend, pack_bits
+            )
 
             def build(i):
                 mask_i = shards.mask[i]
@@ -574,7 +726,8 @@ def _uses_fused_epilogue(art, spec) -> bool:
 
 def _epilogue_projector(art, noise=None):
     """The woodbury quad-form projector ``P = (U - U M^{-1} U)/s2`` per
-    expert — the QUERY-INDEPENDENT half of the fused serve epilogue's
+    expert (:func:`~repro.core.nystrom.nystrom_projector`) — the
+    QUERY-INDEPENDENT half of the fused serve epilogue's
     operand set (it depends only on the artifact's cached factors and
     noise).  The single-tenant serve path rebuilds it inside each predict;
     the fleet stack (:mod:`repro.core.fleet`) precomputes it ONCE per
@@ -584,9 +737,7 @@ def _epilogue_projector(art, noise=None):
         noise = jnp.exp(art.params.log_noise)
     f = art.factors
     s2 = noise + DEFAULT_JITTER
-    return jax.vmap(
-        lambda U, Lm: (U - U @ jax.scipy.linalg.cho_solve((Lm, True), U)) / s2
-    )(f["U"], f["L_M"])
+    return jax.vmap(lambda Lm: nystrom_projector(Lm, s2))(f["L_M"])
 
 
 def _fused_epilogue_operands(art, X_star, sq_star, g_ss, noise, avail,
@@ -684,11 +835,9 @@ def _update_broadcast_jit(art, X_new, y_new, j, pre):
             "L_KK": fac["L_KK"], "W": W2, "L_M": L_M2,
             "alpha": nystrom_kinv(W2, L_M2, s2, y2),
         }
-        if "U" in fac:  # fused-serve cache rides along: U grows by the new
-            # columns' outer product (exact — appended W columns), walpha
+        if "Ainv" in fac:  # fused-serve cache rides along: walpha
             # re-contracts against the updated alpha, Ainv never changes
             out["Ainv"] = fac["Ainv"]
-            out["U"] = fac["U"] + W_new @ W_new.T
             out["walpha"] = W2 @ out["alpha"]
         return out
 

@@ -657,11 +657,9 @@ def _update_center_jit(art, X_new, y_new, j, pre):
         f["W"] = jax.lax.dynamic_update_slice(f["W"], W_new, (0, pos))
         f["L_M"] = chol_update_rank(f["L_M"], W_new)
         f["alpha"] = nystrom_kinv(f["W"], f["L_M"], s2, y2)
-        if "U" in f:
-            # fused-epilogue cache maintenance: U takes the same rank-n_new
-            # update as L_M (padded W columns are zero, so the incremental
-            # form is exact); walpha is an O(K C) recompute; Ainv is fixed
-            f["U"] = f["U"] + W_new @ W_new.T
+        if "Ainv" in f:
+            # fused-epilogue cache maintenance: walpha is an O(K C)
+            # recompute; Ainv is fixed
             f["walpha"] = f["W"] @ f["alpha"]
     elif art.gram_mode == "direct":
         # the validity mask zeroes cross-covariances against padded slots

@@ -326,9 +326,8 @@ def _update_mesh_impl(art, X_new, y_new, j, pre):
                 "L_KK": fac_i["L_KK"], "W": W2, "L_M": L_M2,
                 "alpha": nystrom_kinv(W2, L_M2, s2, y2r),
             }
-            if "U" in fac_i:  # fused-serve cache rides along device-local
+            if "Ainv" in fac_i:  # fused-serve cache rides along device-local
                 fac2["Ainv"] = fac_i["Ainv"]
-                fac2["U"] = fac_i["U"] + W_new @ W_new.T
                 fac2["walpha"] = W2 @ fac2["alpha"]
             return jax.tree.map(lambda a: a[None], fac2)
 

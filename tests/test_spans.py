@@ -13,6 +13,7 @@ from repro.core.protocols import base
 
 PHASES = ("repro.fit.wire", "repro.fit.train", "repro.fit.factors")
 MARKER = "repro.fit.train.program"
+GROUP = "repro.fit.factors.group"
 SMALL = dict(bits_per_sample=8, steps=8)
 
 
@@ -79,13 +80,20 @@ def test_one_root_per_fit_with_distinct_ids(traced):
 
 
 def test_phases_nest_in_their_root_in_order(traced):
-    _, got, _ = traced
+    """The three phases in order, the training marker, and in a broadcast
+    fit one span per call of the factor build's group program, inside
+    ``repro.fit.factors``."""
+    protocol, got, _ = traced
     roots = [s for s in got if s[0] == "repro.fit"]
     for _, r0, r1, fit, _ in roots:
         inside = [s for s in got if s[0] != "repro.fit" and r0 <= s[1] < r1]
         phases = [s for s in inside if s[0] in PHASES]
         assert tuple(s[0] for s in phases) == PHASES
-        assert [s[0] for s in inside if s[0] not in PHASES] == [MARKER]
+        groups = [s for s in inside if s[0] == GROUP]
+        assert [s[0] for s in inside if s[0] not in PHASES + (GROUP,)] == [MARKER]
+        assert bool(groups) == (protocol == "broadcast")
+        _, f0, f1, _, _ = phases[-1]
+        assert all(f0 <= g0 <= g1 <= f1 for _, g0, g1, _, _ in groups)
         for name, s0, s1, sid, _ in inside:
             assert r0 <= s0 <= s1 <= r1, name
             assert sid == fit, name

@@ -68,8 +68,8 @@ def test_machine0_assembly_matches_the_eager_concatenation(path):
     ip_peers = np.einsum("jnd,md->jnm", dec, X[0])
     if path == "batched":
         A = np.einsum("ind,imd->inm", X, X)
-        B = np.einsum("jnd,imd->jinm", dec, X)
-        got = broadcast._train_operands0(A, B, sq_exact, sq_dec, y,
+        B0 = np.einsum("jnd,md->jnm", dec, X[0])  # machine 0's wire column
+        got = broadcast._train_operands0(A, B0, sq_exact, sq_dec, y,
                                          lengths=lengths)
     else:
         got = broadcast._mesh_train_operands0(X, dec, sq_exact, sq_dec, y,
