@@ -209,6 +209,18 @@ def _receiver_products(X_recv, mask, wire: WireState, backend: str,
     return jnp.einsum("jnd,imd->jinm", wire.decoded, X_recv)
 
 
+def _receiver_decodes(wire: WireState, receivers: int, backend: str) -> int:
+    """Row-tile decodes of the ``qgram_packed`` kernel in one
+    :func:`_receiver_products` call for ``receivers`` receivers: one kernel
+    call per sender and receiver, 0 where no kernel runs."""
+    if backend != "pallas":
+        return 0
+    from ...kernels.qgram.ops import qgram_packed_decodes
+
+    m = wire.codes.shape[0]
+    return m * receivers * qgram_packed_decodes(wire.codes.shape[1:])
+
+
 @partial(jax.jit, static_argnames=("backend", "pack_bits"))
 def _train_inner_products(X, mask, wire: WireState, backend: str,
                           pack_bits: int = 0):
@@ -401,8 +413,10 @@ def _build_factors(p, A, X, mask, wire: WireState, sq_exact, sq_dec, y_flat,
     m = X.shape[0]
     args = (p, A, X, mask, wire, sq_exact, sq_dec, y_flat)
     factors = broadcast_factor_buffers(*args, group=group, **static)
+    decodes = _receiver_decodes(wire, group, static["backend"])
+    stats = {"qgram_decodes": decodes} if decodes else {}
     for g in range(-(-m // group)):
-        with span("fit.factors.group"):
+        with span("fit.factors.group", **stats):
             factors = broadcast_factor_group(
                 factors, min(g * group, m - group), *args, group=group, **static
             )
@@ -514,7 +528,7 @@ def broadcast_gp(
 def _fit_broadcast(parts, cfg, params=None) -> FittedProtocol:
     from ...comm.accounting import row_bits
 
-    with span("fit.wire"):
+    with span("fit.wire") as wire_span:
         parts, _ = base._apply_fit_faults(parts, cfg)
         m = len(parts)
         shards = pad_parts(parts)
@@ -547,6 +561,9 @@ def _fit_broadcast(parts, cfg, params=None) -> FittedProtocol:
             A, B0 = _train_inner_products(
                 shards.X, shards.mask, wire_state, gram_backend, pack_bits
             )
+            decodes = _receiver_decodes(wire_state, 1, gram_backend)
+            if decodes:
+                wire_span.set_metadata(qgram_decodes=decodes)
 
     with span("fit.train"):
         # ---- train shared hypers at machine 0 on its completed Nyström gram ----

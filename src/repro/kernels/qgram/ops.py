@@ -198,6 +198,17 @@ def qgram_packed(
     )
 
 
+def qgram_packed_decodes(words_shape) -> int:
+    """Row-tile decodes one traced :func:`qgram_packed` call on words of
+    shape ``(n, W)`` makes: one per row tile of the default block's grid,
+    however many column tiles it has; 0 where the call runs the XLA program
+    instead."""
+    n, n_words = words_shape[-2:]
+    if n_words == 0 or runtime.choose().kind == "xla":
+        return 0
+    return -(-n // DEFAULT_BLOCK_PACKED[0])
+
+
 def qgram_packed_batched(words, rates, scaled_cents, y, *, total_bits, mask=None, **kw):
     """vmapped :func:`qgram_packed` over a leading machine axis.
 

@@ -10,7 +10,12 @@ nor the fp32 reconstruction ever exists in HBM.
 
 Grid (n/bn, p/bp); d and W are NOT tiled (W is 1-2 words for paper rates,
 d <= a few hundred), so each (i, j) program writes its output tile once —
-no cross-step accumulator.  The per-dimension bit layout arrives as a small
+no cross-step accumulator.  The decode depends on the row tile alone: the
+first column step of each row (j == 0) decodes it into a (bn, d) VMEM
+scratch and every step of the row multiplies that scratch against its y
+tile, so a call decodes n/bn row tiles, not n/bn x p/bp.  The column axis is
+the grid's last and "arbitrary" (its steps run in order on one core); the
+row axis stays "parallel".  The per-dimension bit layout arrives as a small
 ``meta`` operand (word index / bit offset / width per dimension, possibly
 traced); word selection is a static W-step select loop, not a dynamic
 gather, so the kernel lowers on TPU as well as in interpret mode.
@@ -22,6 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 DEFAULT_BLOCK_PACKED = (128, 128)  # (bn, bp)
@@ -29,9 +35,8 @@ DEFAULT_ECHUNK = 128
 _WORD = 32
 
 
-def _qgram_packed_kernel(
-    words_ref, meta_ref, cents_ref, y_ref, mask_ref, o_ref, *, echunk: int
-):
+def _decode_rows(words_ref, meta_ref, cents_ref, mask_ref, *, echunk: int):
+    """The row tile's (bn, d) reconstruction: unpack, one-hot decode, mask."""
     words = words_ref[...]  # (bn, W) uint32
     W = words.shape[1]
     word_idx = meta_ref[0, :]  # (d,) int32
@@ -74,9 +79,21 @@ def _qgram_packed_kernel(
     xhat = jax.lax.fori_loop(
         0, n_chunks, body, jnp.zeros(codes.shape, dtype=jnp.float32)
     )  # (bn, d) decoded in VMEM — codes and x̂ never touch HBM
-    xhat = xhat * mask_ref[...]  # (bn, 1): masked rows contribute zero rows
+    return xhat * mask_ref[...]  # (bn, 1): masked rows contribute zero rows
+
+
+def _qgram_packed_kernel(
+    words_ref, meta_ref, cents_ref, y_ref, mask_ref, o_ref, xhat_ref, *,
+    echunk: int,
+):
+    @pl.when(pl.program_id(1) == 0)
+    def _decode():  # once per row tile, kept for the row's column steps
+        xhat_ref[...] = _decode_rows(
+            words_ref, meta_ref, cents_ref, mask_ref, echunk=echunk
+        )
+
     o_ref[...] = jax.lax.dot_general(
-        xhat,
+        xhat_ref[...],
         y_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -108,5 +125,9 @@ def qgram_packed_pallas(
         ],
         out_specs=pl.BlockSpec((bn, bp), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, p), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bn, y.shape[1]), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
     )(words, meta, scaled_cents, y, mask)

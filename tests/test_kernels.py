@@ -91,7 +91,12 @@ def test_qgram_fused_matches_ref(n, d, p, bits):
     np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-3)
 
 
-@pytest.mark.parametrize("n,d,p,bits", [(64, 8, 32, 24), (130, 20, 33, 60), (50, 6, 20, 0)])
+@pytest.mark.parametrize("n,d,p,bits", [
+    (64, 8, 32, 24), (130, 20, 33, 60), (50, 6, 20, 0),
+    # 3 row tiles x 3 column tiles of the default (128, 128) block: each row
+    # tile is decoded at its first column step and reused by the next two
+    (260, 21, 300, 64), (300, 8, 260, 25),
+])
 def test_qgram_packed_matches_ref(n, d, p, bits):
     """The packed-word kernel (unpack in-block, shift/mask, one-hot decode)
     against the three-step oracle — Pallas interpret AND the XLA fallback."""
@@ -116,6 +121,49 @@ def test_qgram_packed_matches_ref(n, d, p, bits):
             qgram_packed(words, jnp.asarray(rates), cents, y, interpret=True, **kw)
         )
         np.testing.assert_allclose(out_pal, ref, rtol=1e-4, atol=1e-3)
+
+
+def test_receiver_products_kernel_matches_the_decoded_wire(monkeypatch):
+    """The broadcast wire's inner products (``_receiver_products``) through
+    the packed kernel under its two vmaps, senders x receivers, against the
+    XLA branch's product of the decoded wire: 3 senders, 2 receivers, shards
+    of 139-140 rows (two row tiles), d=21 at R=64 (two-word rows)."""
+    from repro.core import split_machines
+    from repro.core.distributed_gp import _run_wire_protocol, pad_parts
+    from repro.core.protocols import broadcast
+
+    rng = np.random.default_rng(5)
+    X = rng.normal(size=(419, 21)).astype(np.float32)
+    y = np.sin(X.sum(1)).astype(np.float32)
+    shards = pad_parts(split_machines(X, y, 3, jax.random.PRNGKey(5)))
+    assert shards.X.shape[1] > 128
+    wire = _run_wire_protocol(shards.X, shards.mask, 64, 12, "broadcast", 0)
+    assert wire.codes.shape[-1] == 2
+    recv = shards.X[1:]
+    want = np.asarray(broadcast._receiver_products(recv, shards.mask, wire, "xla"))
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    got = np.asarray(broadcast._receiver_products(
+        recv, shards.mask, wire, "pallas", pack_bits=64))
+    assert got.shape == want.shape == (3, 2) + (shards.X.shape[1],) * 2
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
+    # one decode per sender, receiver and row tile
+    assert broadcast._receiver_decodes(wire, 2, "pallas") == 3 * 2 * 2
+    assert broadcast._receiver_decodes(wire, 2, "xla") == 0
+
+
+def test_qgram_packed_decodes_at_the_cells_shapes(monkeypatch):
+    """Row-tile decodes a call makes: n_pad/bn, whatever the column count.
+    sarcos-refit's blocks (1,113 rows of two words) take 9, broadcast-refit's
+    (250 rows of one word) 2; an XLA call and zero-rate rows take none."""
+    from repro.kernels.qgram.ops import qgram_packed_decodes
+
+    on_chip = jax.default_backend() == "tpu"
+    monkeypatch.delenv("REPRO_FORCE_PALLAS", raising=False)
+    assert qgram_packed_decodes((1113, 2)) == (9 if on_chip else 0)
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    assert qgram_packed_decodes((1113, 2)) == 9
+    assert qgram_packed_decodes((250, 1)) == 2
+    assert qgram_packed_decodes((250, 0)) == 0
 
 
 def test_qgram_packed_equals_unpacked_qgram():

@@ -133,3 +133,32 @@ def test_training_scan_has_a_stable_program_name(traced):
     assert len(train) == len(roots) == 2
     for _, t0, t1, *_ in train:  # dispatched in every fit
         assert any(t0 <= s < t1 for s in dispatched)
+
+
+def test_broadcast_spans_count_the_kernels_decodes(monkeypatch, tmp_path):
+    """With the packed kernel on the path (interpret mode here), the wire
+    span carries the row-tile decodes of machine 0's column of the wire
+    products and each group span its group's: m senders x 1 receiver and
+    m x k receivers, two row tiles a block at 150-row shards."""
+    from jax.profiler import ProfileData
+
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    X, y = _data(n=600)
+    est = DistributedGP(DGPConfig(protocol="broadcast", gram_backend="pallas",
+                                  **SMALL))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jax.block_until_ready(jax.tree_util.tree_leaves(
+            est.fit(X, y, 4, key=jax.random.PRNGKey(2))))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")
+    decodes = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                st = dict(e.stats)
+                if "qgram_decodes" in st:
+                    decodes.setdefault(e.name, []).append(st["qgram_decodes"])
+    # one group of all 4 receivers where the device reports no memory limit
+    assert decodes == {"repro.fit.wire": [4 * 2], GROUP: [4 * 4 * 2]}
