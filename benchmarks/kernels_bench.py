@@ -16,7 +16,6 @@ from repro.kernels.gram.ops import gram
 from repro.kernels.gram.ref import gram_ref
 from repro.kernels.quant.ops import encode, decode, build_scaled_tables
 from repro.kernels.qgram.ops import qgram
-from repro.kernels.decode_attn.ops import decode_attn
 from repro.kernels.epilogue.ops import epilogue_moments
 from .common import timed, emit
 
@@ -46,17 +45,6 @@ def main(quick: bool = True):
     unfused_bytes = n * d * 4 * 2 + (n * d * 1 + p * d * 4 + n * p * 4)
     fused_bytes = n * d * 1 + p * d * 4 + n * p * 4
     emit("kernel_qgram_fused", us, traffic_ratio=unfused_bytes / fused_bytes)
-
-    # decode attention: one token vs a 4k KV cache
-    import jax.numpy as jnp
-    B, S, KV, G, hd = (2, 2048, 2, 4, 64) if quick else (8, 8192, 4, 8, 128)
-    q = jnp.asarray(rng.normal(size=(B, KV, G, hd)), jnp.float32)
-    K = jnp.asarray(rng.normal(size=(B, S, KV, hd)), jnp.bfloat16)
-    V = jnp.asarray(rng.normal(size=(B, S, KV, hd)), jnp.bfloat16)
-    kpos = jnp.asarray(np.arange(S)[None].repeat(B, 0), jnp.int32)
-    _, us = timed(lambda: jax.block_until_ready(
-        decode_attn(q, K, V, kpos, S - 1, interpret=True)), repeats=2)
-    emit("kernel_decode_attn", us, kv_bytes=B * S * KV * hd * 2 * 2)
 
     # fused serve epilogue: m experts' cached apply + fusion moments, 1 launch
     m_e, t_e, K_e = (8, 128, 64) if quick else (16, 512, 128)

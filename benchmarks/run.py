@@ -22,7 +22,7 @@ def main() -> None:
                     help="write BENCH_<name>.json per selected benchmark")
     ap.add_argument("--only", default=None,
                     help="comma list of: fig2,fig3,fig4,fig56,fig7,kernels,"
-                         "ablation_bits,roofline,hotpath,serve,mesh,vq,wire,"
+                         "ablation_bits,hotpath,serve,mesh,vq,wire,"
                          "fault,stream,fleet")
     args = ap.parse_args()
     quick = not args.full
@@ -31,7 +31,7 @@ def main() -> None:
 
     setup_compilation_cache()
     from . import fig2_distortion, fig3_pca, fig4_gp1d, fig56_regression, fig7_sparse
-    from . import kernels_bench, roofline, ablation_bits, hotpath_bench, serve_bench
+    from . import kernels_bench, ablation_bits, hotpath_bench, serve_bench
     from . import mesh_bench, vq_bench, wire_bench, fault_bench, stream_bench
     from . import fleet_bench
     from . import common
@@ -44,7 +44,6 @@ def main() -> None:
         "fig7": lambda: fig7_sparse.main(quick=quick),
         "kernels": lambda: kernels_bench.main(quick=quick),
         "ablation_bits": lambda: ablation_bits.main(quick=quick),
-        "roofline": lambda: roofline.main(),
         "hotpath": lambda: hotpath_bench.main(quick=quick),
         "serve": lambda: serve_bench.main(quick=quick),
         "mesh": lambda: mesh_bench.main(quick=quick),

@@ -29,7 +29,7 @@ local-jitter
 xla-env-mutation
     ``os.environ["XLA_FLAGS"]`` is process-global, order-sensitive state;
     only ``compat.force_host_device_count`` may touch it (a stray mutation
-    after backend init silently does nothing — the PR-3 dryrun bug).
+    after backend init silently does nothing).
 device-get-hot-path
     No ``device_get`` in ``kernels/`` at all, and in ``core/protocols/``
     only inside the named host-sync boundary functions (the ledger

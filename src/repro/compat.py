@@ -1,7 +1,6 @@
 """Process-level JAX setup shared by the entry points.
 
 * :func:`make_mesh` — every mesh this repo builds has Auto axes.
-* :func:`cost_analysis_dict` — ``compiled.cost_analysis()`` as a dict.
 * :func:`host_device_count_flags` / :func:`force_host_device_count` — the
   one place that edits ``XLA_FLAGS`` (CPU placeholder devices).
 * :func:`setup_compilation_cache` — the persistent compilation cache at a
@@ -17,7 +16,6 @@ __all__ = [
     "CHECKOUT_ROOT",
     "COMPILATION_CACHE_DIR",
     "make_mesh",
-    "cost_analysis_dict",
     "host_device_count_flags",
     "force_host_device_count",
     "setup_compilation_cache",
@@ -47,8 +45,8 @@ def force_host_device_count(n: int) -> None:
     """Set XLA_FLAGS in os.environ to force ``n`` host devices — must run
     before the jax backend initializes (first device query; importing jax is
     fine).  Only the host (CPU) platform reads the flag; an attached
-    accelerator keeps its own device count.  Shared by launch/dryrun (512
-    placeholder devices) and serve_gp --mesh (one device per machine)."""
+    accelerator keeps its own device count.  Used by serve_gp --mesh (one
+    device per machine)."""
     os.environ["XLA_FLAGS"] = host_device_count_flags(
         n, os.environ.get("XLA_FLAGS", "")
     )
@@ -59,12 +57,6 @@ def make_mesh(axis_shapes, axis_names, **kw):
     mode is not used anywhere in this repo)."""
     auto = (jax.sharding.AxisType.Auto,) * len(axis_names)
     return jax.make_mesh(axis_shapes, axis_names, axis_types=auto, **kw)
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a dict (None on backends that report
-    no cost model)."""
-    return dict(compiled.cost_analysis() or {})
 
 
 def setup_compilation_cache() -> str | None:

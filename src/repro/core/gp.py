@@ -231,8 +231,8 @@ class GPModel:
 
 def make_adam_step(loss: Callable, lr: float) -> Callable:
     """One Adam update ``step(i, params, m, v) -> (params, m, v)`` for the
-    given scalar loss — minimal inline Adam (repro.optim is for the NN stack;
-    keep core standalone).  Shared by both of train_gp's programs."""
+    given scalar loss — minimal inline Adam (keeps core standalone of any
+    optimizer library).  Shared by both of train_gp's programs."""
     b1, b2, eps = 0.9, 0.999, 1e-8
 
     def step(i, p, m, v):

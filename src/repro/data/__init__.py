@@ -1,7 +1,5 @@
 from .synthetic import (
-    lm_batch_stream,
     regression_dataset,
     DATASET_SPECS,
     mnist_like_two_digits,
 )
-from .pipeline import ShardedBatcher

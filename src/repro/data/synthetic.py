@@ -1,7 +1,5 @@
 """Deterministic synthetic data generators.
 
-* ``lm_batch_stream`` — token batches for the transformer drivers (Zipf-ish
-  marginal + Markov bigram structure so the loss has signal).
 * ``regression_dataset`` — GP-regression datasets statistically matched to the
   paper's benchmarks (same n/d/noise regime); real files are used instead when
   present (benchmarks pass --data-dir).
@@ -10,12 +8,9 @@
 """
 from __future__ import annotations
 
-import dataclasses
 import zlib
 
 import numpy as np
-import jax
-import jax.numpy as jnp
 
 DATASET_SPECS = {
     # name: (n_train, n_test, d) as in the paper §6
@@ -23,28 +18,6 @@ DATASET_SPECS = {
     "kin40k": (1000, 30000, 8),
     "abalone": (1000, 1044, 8),
 }
-
-
-def lm_batch_stream(vocab_size: int, batch: int, seq: int, seed: int = 0):
-    """Infinite deterministic stream of (tokens, labels) int32 batches."""
-    rng = np.random.default_rng(seed)
-    # fixed random bigram preference: tok -> preferred successor
-    succ = rng.integers(0, vocab_size, size=vocab_size)
-    step = 0
-    while True:
-        r = np.random.default_rng((seed, step))
-        toks = np.empty((batch, seq + 1), dtype=np.int64)
-        toks[:, 0] = r.zipf(1.3, size=batch) % vocab_size
-        noise = r.random((batch, seq))
-        rand_next = r.integers(0, vocab_size, size=(batch, seq))
-        for t in range(seq):
-            follow = succ[toks[:, t]]
-            toks[:, t + 1] = np.where(noise[:, t] < 0.65, follow, rand_next[:, t])
-        yield {
-            "tokens": jnp.asarray(toks[:, :-1], jnp.int32),
-            "labels": jnp.asarray(toks[:, 1:], jnp.int32),
-        }
-        step += 1
 
 
 def regression_dataset(name: str, seed: int = 0, data_dir: str | None = None,

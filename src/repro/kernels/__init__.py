@@ -3,8 +3,6 @@
 gram/        tiled gram-block  G = X Y^T       (MXU)
 quant/       per-symbol encode/decode (§4.2)   (VPU threshold-count / one-hot)
 qgram/       fused dequantize + gram           (decode in VMEM, no HBM roundtrip)
-decode_attn/ single-token GQA decode attention (online softmax over KV chunks,
-             ring-cache masking via kpos)
 epilogue/    fused Nyström serve epilogue      (cached apply + fusion moments,
              one launch per query batch)
 
@@ -17,5 +15,4 @@ from . import runtime
 from .gram import ops as gram_ops
 from .quant import ops as quant_ops
 from .qgram import ops as qgram_ops
-from .decode_attn import ops as decode_attn_ops
 from .epilogue import ops as epilogue_ops

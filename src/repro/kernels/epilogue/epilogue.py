@@ -1,13 +1,13 @@
-"""Pallas TPU kernel: fused Nyström serve epilogue (decode-attn pattern).
+"""Pallas TPU kernel: fused Nyström serve epilogue.
 
 Grid (m,): one step per expert, streaming that expert's (t, K) cross-gram
 tile and its K x K cached operands HBM->VMEM; the (ROWS, t) fp32 moment
 accumulator lives in the revisited output tile across steps (the same
-output-accumulator-only shape as the gram and decode_attn kernels).  Each
-step runs the expert's cached apply — two MXU matmuls against ``Ainv`` and
-the woodbury projector ``P`` — and folds the resulting predictive straight
-into the fusion's moment rows, so the whole serve tail between the
-cross-gram and ``finalize`` is ONE kernel launch.
+output-accumulator-only shape as the gram kernel).  Each step runs the
+expert's cached apply — two MXU matmuls against ``Ainv`` and the woodbury
+projector ``P`` — and folds the resulting predictive straight into the
+fusion's moment rows, so the whole serve tail between the cross-gram and
+``finalize`` is ONE kernel launch.
 """
 from __future__ import annotations
 

@@ -3,9 +3,8 @@ persistent autotune cache, and the shape-sweep bench harness.
 
 Before this module existed each family (``gram``, ``quant``, ``qgram``)
 re-parsed ``REPRO_FORCE_PALLAS`` and treated ``interpret=None`` slightly
-differently, and ``decode_attn`` had no XLA fallback at all.  The policy now
-lives in exactly one place — :func:`choose` — and is identical for all
-families:
+differently.  The policy now lives in exactly one place — :func:`choose` —
+and is identical for all families:
 
 * ``interpret`` given explicitly -> the Pallas kernel path with that
   interpret flag (the caller is debugging the kernel; policy stays out of

@@ -1,2 +1,2 @@
-"""Drivers: serve_gp (distributed-GP serving), train/serve/dryrun (the
-transformer stack with the GP head).  Modules are runnable via python -m."""
+"""Drivers: serve_gp (distributed-GP serving) and fleet (the multi-tenant
+fleet server).  Modules are runnable via python -m."""

@@ -2,11 +2,11 @@ import os
 
 # Give in-process tests a multi-device CPU platform.  This must run before the
 # first jax import (conftest is imported before any test module).  Subprocess
-# tests (test_comm / test_mesh_gp / test_qcomm) overwrite XLA_FLAGS themselves,
-# and repro.launch.dryrun only forces its 512 placeholder devices under
-# __main__ (force_placeholder_devices), so importing it never stomps this
-# setting.  In-process mesh tests (test_conformance, the in-process halves of
-# test_comm) rely on these 8 devices.
+# tests (test_comm / test_mesh_gp / test_qpsum_training) overwrite XLA_FLAGS
+# themselves, and the entry points force a device count only when run as a
+# command, so importing one never stomps this setting.  In-process mesh tests
+# (test_conformance, the in-process halves of test_comm) rely on these 8
+# devices.
 if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"

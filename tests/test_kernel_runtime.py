@@ -21,10 +21,19 @@ import pytest
 
 from repro.kernels import runtime
 
-FAMILIES = (
-    "gram", "quant_encode", "quant_decode", "qgram", "qgram_packed",
-    "decode_attn", "epilogue",
-)
+# each family's public op and the Pallas path that op would take:
+# (module, public op, kernel-path function)
+PUBLIC_OPS = {
+    "gram": ("repro.kernels.gram.ops", "gram", "_gram_kernel_path"),
+    "quant_encode": ("repro.kernels.quant.ops", "encode", "_encode_kernel_path"),
+    "quant_decode": ("repro.kernels.quant.ops", "decode", "_decode_kernel_path"),
+    "qgram": ("repro.kernels.qgram.ops", "qgram", "_qgram_kernel_path"),
+    "qgram_packed": ("repro.kernels.qgram.ops", "qgram_packed",
+                     "_qgram_packed_kernel_path"),
+    "epilogue": ("repro.kernels.epilogue.ops", "epilogue_moments",
+                 "_epilogue_kernel_path"),
+}
+FAMILIES = tuple(PUBLIC_OPS)
 
 
 # --------------------------------------------------------------------------
@@ -97,13 +106,6 @@ def _family_args(name, rng):
     if name == "qgram_packed":
         words = js.pack_codes(codes, jnp.asarray(rates), total_bits=bits)
         return (words, jnp.asarray(rates), cents, y), {"total_bits": bits}
-    if name == "decode_attn":
-        B, S, KV, G, hd = 2, 24, 2, 2, 16
-        q = rng.normal(size=(B, KV, G, hd)).astype(np.float32)
-        K = rng.normal(size=(B, S, KV, hd)).astype(np.float32)
-        V = rng.normal(size=(B, S, KV, hd)).astype(np.float32)
-        kpos = np.broadcast_to(np.arange(S, dtype=np.int32), (B, S)).copy()
-        return (q, K, V, kpos, S - 1), {}
     if name == "epilogue":
         m, t, K = 3, 17, 11
         G = rng.normal(size=(m, t, K)).astype(np.float32)
@@ -127,29 +129,37 @@ def test_family_backends_match_ref(name):
     spec = runtime.kernel_op(name)
     args, kw = _family_args(name, rng)
     ref = spec.ref(*args, **kw)
-    pal = spec.pallas(*args, interpret=True, **kw)
-    xla = spec.xla(*args, **kw)
-    for got in (pal, xla):
-        jax.tree.map(
-            lambda a, b: np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-3
-            ),
-            got, ref,
-        )
+    _assert_matches_ref(spec.pallas(*args, interpret=True, **kw), ref)
+    _assert_matches_ref(spec.xla(*args, **kw), ref)
 
 
-def test_decode_attn_xla_fallback_serves_off_tpu(monkeypatch):
-    """decode_attn historically had NO fallback: interpret=None off-TPU now
-    runs the jitted reference instead of raising/interpreting."""
+def _assert_matches_ref(got, ref):
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-3
+        ),
+        got, ref,
+    )
+
+
+@pytest.mark.parametrize("name", FAMILIES)
+def test_family_xla_fallback_serves_off_tpu(name, monkeypatch):
+    """Each public op, called with ``interpret=None`` off the TPU, runs the
+    family's XLA fallback (its Pallas path is made to fail) and equals the
+    family's ``ref``."""
+    import importlib
+
     monkeypatch.delenv("REPRO_FORCE_PALLAS", raising=False)
-    from repro.kernels.decode_attn.ops import decode_attn
+    module, op, kernel_path = PUBLIC_OPS[name]
+    mod = importlib.import_module(module)
 
-    rng = np.random.default_rng(3)
-    (q, K, V, kpos, pos), _ = _family_args("decode_attn", rng)
-    out = decode_attn(q, K, V, kpos, pos)  # interpret=None -> xla
-    ref = runtime.kernel_op("decode_attn").ref(q, K, V, kpos, pos)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
+    def no_pallas(*a, **k):
+        raise AssertionError(f"{op} took the Pallas path off the TPU")
+
+    monkeypatch.setattr(mod, kernel_path, no_pallas)
+    args, kw = _family_args(name, np.random.default_rng(FAMILIES.index(name)))
+    got = getattr(mod, op)(*args, **kw)  # interpret=None -> xla
+    _assert_matches_ref(got, runtime.kernel_op(name).ref(*args, **kw))
 
 
 # --------------------------------------------------------------------------
