@@ -194,31 +194,33 @@ def _receiver_products(X_recv, mask, wire: WireState, backend: str,
     products.  backend="pallas" computes them straight from the PACKED wire
     words with the fused unpack+dequantize+gram kernel (``pack_bits``: the
     static row bit budget of the packed plane; ``mask`` (m, n_pad) zeroes the
-    senders' padded rows)."""
+    senders' padded rows): one call per sender, the k receivers' projections
+    stacked along its columns, so each sender's rows are decoded once for all
+    k receivers."""
     if backend == "pallas":
         from ...kernels.qgram.ops import qgram_packed
 
+        k, n_pad, _ = X_recv.shape
         proj = jnp.einsum("ind,jde->jine", X_recv, wire.T_inv)  # (m_j, k, n, d)
-        return jax.vmap(
-            lambda w, r, t, mk, ys: jax.vmap(
-                lambda yy: qgram_packed(
-                    w, r, t, yy, total_bits=pack_bits, mask=mk
-                )
-            )(ys)
-        )(wire.codes, wire.rates, wire.scaled_cents, mask, proj)
+        stacked = jax.vmap(
+            lambda w, r, t, mk, ys: qgram_packed(
+                w, r, t, ys, total_bits=pack_bits, mask=mk
+            )
+        )(wire.codes, wire.rates, wire.scaled_cents, mask,
+          proj.reshape(proj.shape[0], k * n_pad, -1))  # (m_j, n, k*n)
+        return stacked.reshape(-1, n_pad, k, n_pad).transpose(0, 2, 1, 3)
     return jnp.einsum("jnd,imd->jinm", wire.decoded, X_recv)
 
 
-def _receiver_decodes(wire: WireState, receivers: int, backend: str) -> int:
+def _receiver_decodes(wire: WireState, backend: str) -> int:
     """Row-tile decodes of the ``qgram_packed`` kernel in one
-    :func:`_receiver_products` call for ``receivers`` receivers: one kernel
-    call per sender and receiver, 0 where no kernel runs."""
+    :func:`_receiver_products` call, however many receivers it is given: one
+    kernel call per sender, 0 where no kernel runs."""
     if backend != "pallas":
         return 0
     from ...kernels.qgram.ops import qgram_packed_decodes
 
-    m = wire.codes.shape[0]
-    return m * receivers * qgram_packed_decodes(wire.codes.shape[1:])
+    return wire.codes.shape[0] * qgram_packed_decodes(wire.codes.shape[1:])
 
 
 @partial(jax.jit, static_argnames=("backend", "pack_bits"))
@@ -413,7 +415,7 @@ def _build_factors(p, A, X, mask, wire: WireState, sq_exact, sq_dec, y_flat,
     m = X.shape[0]
     args = (p, A, X, mask, wire, sq_exact, sq_dec, y_flat)
     factors = broadcast_factor_buffers(*args, group=group, **static)
-    decodes = _receiver_decodes(wire, group, static["backend"])
+    decodes = _receiver_decodes(wire, static["backend"])
     stats = {"qgram_decodes": decodes} if decodes else {}
     for g in range(-(-m // group)):
         with span("fit.factors.group", **stats):
@@ -561,7 +563,7 @@ def _fit_broadcast(parts, cfg, params=None) -> FittedProtocol:
             A, B0 = _train_inner_products(
                 shards.X, shards.mask, wire_state, gram_backend, pack_bits
             )
-            decodes = _receiver_decodes(wire_state, 1, gram_backend)
+            decodes = _receiver_decodes(wire_state, gram_backend)
             if decodes:
                 wire_span.set_metadata(qgram_decodes=decodes)
 

@@ -123,22 +123,31 @@ def test_qgram_packed_matches_ref(n, d, p, bits):
         np.testing.assert_allclose(out_pal, ref, rtol=1e-4, atol=1e-3)
 
 
-def test_receiver_products_kernel_matches_the_decoded_wire(monkeypatch):
-    """The broadcast wire's inner products (``_receiver_products``) through
-    the packed kernel under its two vmaps, senders x receivers, against the
-    XLA branch's product of the decoded wire: 3 senders, 2 receivers, shards
-    of 139-140 rows (two row tiles), d=21 at R=64 (two-word rows)."""
+def _two_tile_wire(seed):
+    """3 machines' padded shards of 139-140 rows (two row tiles, n_pad = 140)
+    at d=21 and their broadcast wire at R=64 (two-word rows)."""
     from repro.core import split_machines
     from repro.core.distributed_gp import _run_wire_protocol, pad_parts
-    from repro.core.protocols import broadcast
 
-    rng = np.random.default_rng(5)
+    rng = np.random.default_rng(seed)
     X = rng.normal(size=(419, 21)).astype(np.float32)
     y = np.sin(X.sum(1)).astype(np.float32)
-    shards = pad_parts(split_machines(X, y, 3, jax.random.PRNGKey(5)))
-    assert shards.X.shape[1] > 128
+    shards = pad_parts(split_machines(X, y, 3, jax.random.PRNGKey(seed)))
+    assert shards.X.shape[1] == 140
     wire = _run_wire_protocol(shards.X, shards.mask, 64, 12, "broadcast", 0)
     assert wire.codes.shape[-1] == 2
+    return shards, wire
+
+
+def test_receiver_products_kernel_matches_the_decoded_wire(monkeypatch):
+    """The broadcast wire's inner products (``_receiver_products``) through
+    the packed kernel, one call per sender with the receivers stacked along
+    its columns, against the XLA branch's product of the decoded wire: 3
+    senders, 2 receivers, shards of 139-140 rows (two row tiles), d=21 at
+    R=64 (two-word rows)."""
+    from repro.core.protocols import broadcast
+
+    shards, wire = _two_tile_wire(5)
     recv = shards.X[1:]
     want = np.asarray(broadcast._receiver_products(recv, shards.mask, wire, "xla"))
     monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
@@ -146,9 +155,41 @@ def test_receiver_products_kernel_matches_the_decoded_wire(monkeypatch):
         recv, shards.mask, wire, "pallas", pack_bits=64))
     assert got.shape == want.shape == (3, 2) + (shards.X.shape[1],) * 2
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
-    # one decode per sender, receiver and row tile
-    assert broadcast._receiver_decodes(wire, 2, "pallas") == 3 * 2 * 2
-    assert broadcast._receiver_decodes(wire, 2, "xla") == 0
+    # one decode per sender and row tile, however many receivers
+    assert broadcast._receiver_decodes(wire, "pallas") == 3 * 2
+    assert broadcast._receiver_decodes(wire, "xla") == 0
+
+
+@pytest.mark.parametrize("k", [1, 2, 3])
+def test_stacked_receiver_products_match_the_xla_branch(monkeypatch, k):
+    """k receivers stacked along one kernel call per sender: the same
+    (m, k, n_pad, n_pad) products as the XLA branch's einsum.  n_pad = 140
+    is no multiple of the 128-column tile, so for k > 1 column tiles
+    straddle two receivers; d=21 at R=64 gives two-word rows.  The traced
+    program holds one ``pallas_call``, batched over the senders, whose grid
+    has no receiver axis."""
+    from repro.analysis.jaxpr_walk import walk_jaxpr
+    from repro.core.protocols import broadcast
+    from repro.kernels.qgram.packed import DEFAULT_BLOCK_PACKED
+
+    shards, wire = _two_tile_wire(11)
+    n_pad = shards.X.shape[1]
+    recv = shards.X[:k]
+    want = np.asarray(broadcast._receiver_products(recv, shards.mask, wire, "xla"))
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    products = lambda r: broadcast._receiver_products(
+        r, shards.mask, wire, "pallas", pack_bits=64)
+    got = np.asarray(products(recv))
+    assert got.shape == want.shape == (3, k, n_pad, n_pad)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
+
+    (call,) = [e for e in walk_jaxpr(jax.make_jaxpr(products)(recv))
+               if e.primitive.name == "pallas_call"]
+    bn, bp = DEFAULT_BLOCK_PACKED
+    # (senders, row tiles, column tiles of all k receivers); a vmap over
+    # the receivers would add a fourth axis of k
+    assert tuple(call.params["grid_mapping"].grid) == (
+        3, -(-n_pad // bn), -(-k * n_pad // bp))
 
 
 def test_qgram_packed_decodes_at_the_cells_shapes(monkeypatch):

@@ -138,8 +138,9 @@ def test_training_scan_has_a_stable_program_name(traced):
 def test_broadcast_spans_count_the_kernels_decodes(monkeypatch, tmp_path):
     """With the packed kernel on the path (interpret mode here), the wire
     span carries the row-tile decodes of machine 0's column of the wire
-    products and each group span its group's: m senders x 1 receiver and
-    m x k receivers, two row tiles a block at 150-row shards."""
+    products and each group span its group's: one kernel call per sender
+    in each, whatever the group's receivers, two row tiles a block at
+    150-row shards."""
     from jax.profiler import ProfileData
 
     monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
@@ -161,4 +162,4 @@ def test_broadcast_spans_count_the_kernels_decodes(monkeypatch, tmp_path):
                 if "qgram_decodes" in st:
                     decodes.setdefault(e.name, []).append(st["qgram_decodes"])
     # one group of all 4 receivers where the device reports no memory limit
-    assert decodes == {"repro.fit.wire": [4 * 2], GROUP: [4 * 4 * 2]}
+    assert decodes == {"repro.fit.wire": [4 * 2], GROUP: [4 * 2]}
